@@ -17,7 +17,16 @@ from __future__ import annotations
 import argparse
 import sys
 
-from benchmarks.common import header
+# Every suite runs in this one process: a child started after the parent
+# has touched JAX could not reach a chip the parent holds. The sharded
+# suite meshes over 8 faked CPU devices on a CPU host, so the flag has to
+# be set before JAX starts; it only touches the host platform.
+from benchmarks.host_devices import fake_host_devices
+
+fake_host_devices(8)
+
+from benchmarks.common import header  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 #: name -> (runner, artifacts it emits). Order is the run order: cheap
 #: smoke/figure rows first, the campaign sweeps (slowest) last.
@@ -100,18 +109,8 @@ def _hetero() -> None:
 
 @_suite("sharded", ("BENCH_sharded_campaign.json",))
 def _sharded() -> None:
-    # Runs in a subprocess: the XLA device count locks at the first in-process
-    # jax init, so the 8-device fake topology can't be set up from here.
-    import os
-    import subprocess
-    env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    if "--xla_force_host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8").strip()
-    subprocess.run(
-        [sys.executable, "benchmarks/sharded_campaign.py"],
-        env=env, check=True)
+    from benchmarks import sharded_campaign
+    sharded_campaign.main([])
 
 
 def main(argv: list[str] | None = None) -> int:
@@ -134,6 +133,7 @@ def main(argv: list[str] | None = None) -> int:
     if unknown:
         ap.error(f"unknown suite(s) {unknown}; choices: {list(SUITES)}")
 
+    enable_compile_cache()
     header()
     emitted: list[str] = []
     for name in names:

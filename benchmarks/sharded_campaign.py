@@ -26,14 +26,9 @@ Run:  PYTHONPATH=src:. python benchmarks/sharded_campaign.py
 """
 from __future__ import annotations
 
-import os
-import sys
+from benchmarks.host_devices import fake_host_devices
 
-if "jax" not in sys.modules:  # must precede jax import to take effect
-    _flags = os.environ.get("XLA_FLAGS", "")
-    if "--xla_force_host_platform_device_count" not in _flags:
-        os.environ["XLA_FLAGS"] = (
-            _flags + " --xla_force_host_platform_device_count=8").strip()
+fake_host_devices(8)  # must precede the jax import to take effect
 
 import argparse
 import dataclasses

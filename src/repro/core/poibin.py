@@ -4,7 +4,7 @@ Paper eq. (9): closed-form DFT expression for the pmf of ``m = sum_i X_i``
 with independent ``X_i ~ Bernoulli(p_i)`` (Fernandez & Williams, 2010), and
 eq. (8): the expected task duration ``E[D] = sum_k d(k) P[m=k]``.
 
-Everything scalar here is pure JAX (complex64/complex128 DFT) and
+Everything scalar here is pure JAX (a real-arithmetic DFT) and
 differentiable in the participation probabilities — the NE solver in
 :mod:`repro.core.game` differentiates straight through this pmf.
 
@@ -36,6 +36,7 @@ __all__ = [
 ]
 
 
+@jax.jit
 def poibin_pmf(p: jax.Array) -> jax.Array:
     """Pmf of the Poisson-Binomial distribution via the DFT closed form.
 
@@ -44,6 +45,10 @@ def poibin_pmf(p: jax.Array) -> jax.Array:
         P[m] = (1/(N+1)) * sum_{n=0}^{N} exp(-j 2 pi n m/(N+1))
                   * prod_{k=1}^{N} [p_k (exp(j 2 pi n/(N+1)) - 1) + 1]
 
+    in real arithmetic: the characteristic function is carried as a
+    (re, im) pair, as in the Pallas kernel, because the TPU compiler has no
+    complex128.
+
     Args:
         p: ``(N,)`` participation probabilities in [0, 1].
 
@@ -51,18 +56,24 @@ def poibin_pmf(p: jax.Array) -> jax.Array:
         ``(N+1,)`` real pmf over m = 0..N.
     """
     p = jnp.asarray(p)
-    n_nodes = p.shape[0]
-    size = n_nodes + 1
-    # Characteristic function evaluated on the (N+1)-point unit circle.
-    n = jnp.arange(size)
-    omega = jnp.exp(2j * jnp.pi * n / size)  # (N+1,)
-    # prod_k [p_k (w - 1) + 1] for each frequency.
-    terms = p[None, :] * (omega[:, None] - 1.0) + 1.0  # (N+1, N)
-    # Product via sum of logs is unstable near zeros; direct prod is fine at N<=few hundred.
-    chi = jnp.prod(terms, axis=1)  # (N+1,)
-    m = jnp.arange(size)
-    dft = jnp.exp(-2j * jnp.pi * jnp.outer(m, n) / size)  # (N+1, N+1)
-    pmf = (dft @ chi).real / size
+    size = p.shape[0] + 1
+    # Characteristic function on the (N+1)-point unit circle,
+    # prod_k [p_k (w - 1) + 1] for each frequency w = exp(j 2 pi n/(N+1)).
+    ang = 2 * jnp.pi * jnp.arange(size) / size
+    w_re, w_im = jnp.cos(ang), jnp.sin(ang)
+
+    def factor(chi, p_k):
+        re, im = chi
+        t_re = p_k * (w_re - 1.0) + 1.0
+        t_im = p_k * w_im
+        return (re * t_re - im * t_im, re * t_im + im * t_re), None
+
+    one = jnp.ones((size,), jnp.result_type(p, w_re))
+    (chi_re, chi_im), _ = jax.lax.scan(factor, (one, jnp.zeros_like(one)), p)
+    # Re[sum_n exp(-j theta_mn) chi_n] with theta_mn = 2 pi m n/(N+1).
+    theta = 2 * jnp.pi * jnp.outer(jnp.arange(size), jnp.arange(size)) / size
+    pmf = jnp.sum(jnp.cos(theta) * chi_re + jnp.sin(theta) * chi_im,
+                  axis=1) / size
     # Numerical cleanup: clip tiny negatives, renormalize.
     pmf = jnp.clip(pmf, 0.0, 1.0)
     return pmf / jnp.sum(pmf)

@@ -35,18 +35,6 @@ __all__ = ["fedavg_allreduce_merge", "init_cluster_opt_state",
            "make_cluster_round"]
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """Version shim: ``jax.shard_map(check_vma=...)`` on new JAX,
-    ``jax.experimental.shard_map.shard_map(check_rep=...)`` on old —
-    replication checking off in both (the merge psums by hand)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def fedavg_allreduce_merge(global_params, local_update, mask_local,
                            mesh: Mesh, axes: Sequence[str] = ("data",)):
     """Masked FedAvg across mesh axes via shard_map + psum.
@@ -104,8 +92,9 @@ def fedavg_allreduce_merge(global_params, local_update, mask_local,
         P(),
     )
     out_specs = jax.tree.map(lambda _: P(), global_params)
-    fn = _shard_map(merge_fn, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs)
+    # Replication checking off: the merge psums by hand.
+    fn = jax.shard_map(merge_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(global_params, local_update, mask_local)
 
 

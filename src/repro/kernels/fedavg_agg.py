@@ -5,9 +5,14 @@ parameter vector: out = Σ_i m_i θ_i / Σ_i m_i, falling back to the previous
 global θ when nobody participated. Fusing mask-multiply + reduce + renorm +
 fallback into one pass reads each client parameter exactly once.
 
-* grid = (param_tiles,); each tile loads an (N, BP) client slab + the (BP,)
-  previous-global slice. N ≤ ~64 clients and BP = 2048 fp32 keeps tiles
-  ~0.5 MB in VMEM.
+* The flat parameter vector is laid out lane-dense as (P/128, 128) rows,
+  so every block's last two dims are (BP/128, 128) sublanes × lanes —
+  also when the campaign vmaps the kernel over its scenario batch, which
+  prepends a squeezed batch dim to each block.
+* grid = (param_tiles,); each tile loads an (N, BP/128, 128) client slab
+  + the (BP/128, 128) previous-global slice. N ≤ ~64 clients and
+  BP = 2048 fp32 keeps tiles ~0.5 MB in VMEM. On the chip BP must be a
+  multiple of 1024 (8 sublanes × 128 lanes) or cover all of P.
 * The mask lives in SMEM-friendly (N, 1) layout; participant count is
   reduced in-kernel (N is tiny). Float masks carry participation·weight
   products for the weighted-FedAvg path.
@@ -26,18 +31,22 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+# Constant block index for index maps: an int32 scalar, since a Python 0
+# traces as int64 under x64 and Mosaic rejects 64-bit block indices.
+_ZERO = np.int32(0)
+_LANES = 128
 
 
 def _kernel(global_ref, clients_ref, mask_ref, o_ref):
-    g = global_ref[...].astype(jnp.float32)          # (BP,)
-    c = clients_ref[...].astype(jnp.float32)         # (N, BP)
+    g = global_ref[...].astype(jnp.float32)          # (BR, 128)
+    c = clients_ref[...].astype(jnp.float32)         # (N, BR, 128)
     m = mask_ref[...].astype(jnp.float32)            # (N, 1)
     total = jnp.sum(m)
-    avg = jnp.sum(c * m, axis=0) / jnp.maximum(total, 1e-9)
+    avg = jnp.sum(c * m[:, :, None], axis=0) / jnp.maximum(total, 1e-9)
     o_ref[...] = jnp.where(total > 0, avg, g).astype(o_ref.dtype)
 
 
@@ -46,26 +55,30 @@ def fedavg_agg(global_flat, client_flat, mask, *, block_p: int = 2048,
                interpret: bool = False):
     """global_flat: (P,); client_flat: (N,P); mask: (N,) -> (P,)."""
     n, p = client_flat.shape
-    block_p = min(block_p, p)
-    n_p = pl.cdiv(p, block_p)
-    pad = n_p * block_p - p
+    rows = pl.cdiv(p, _LANES)
+    block_rows = min(max(block_p // _LANES, 1), rows)
+    n_p = pl.cdiv(rows, block_rows)
+    pad = n_p * block_rows * _LANES - p
     if pad:
         global_flat = jnp.pad(global_flat, ((0, pad),))
         client_flat = jnp.pad(client_flat, ((0, 0), (0, pad)))
+    g = global_flat.reshape(n_p * block_rows, _LANES)
+    c = client_flat.reshape(n, n_p * block_rows, _LANES)
     mask2 = mask.astype(jnp.float32).reshape(n, 1)
 
     out = pl.pallas_call(
         _kernel,
         grid=(n_p,),
         in_specs=[
-            pl.BlockSpec((block_p,), lambda i: (i,)),
-            pl.BlockSpec((n, block_p), lambda i: (0, i)),
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),
+            pl.BlockSpec((block_rows, _LANES), lambda i: (i, _ZERO)),
+            pl.BlockSpec((n, block_rows, _LANES),
+                         lambda i: (_ZERO, i, _ZERO)),
+            pl.BlockSpec((n, 1), lambda i: (_ZERO, _ZERO)),
         ],
-        out_specs=pl.BlockSpec((block_p,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_p * block_p,), global_flat.dtype),
-        compiler_params=CompilerParams(
+        out_specs=pl.BlockSpec((block_rows, _LANES), lambda i: (i, _ZERO)),
+        out_shape=jax.ShapeDtypeStruct(g.shape, global_flat.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(global_flat, client_flat, mask2)
-    return out[:p]
+    )(g, c, mask2)
+    return out.reshape(-1)[:p]

@@ -24,12 +24,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
-NEG_INF = -1e30
+# Constant block index for index maps: an int32 scalar, since a Python 0
+# traces as int64 under x64 and Mosaic rejects 64-bit block indices.
+_ZERO = np.int32(0)
+NEG_INF = np.float32(-1e30)
+_FZERO = np.float32(0.0)    # f32 even under x64, like _ZERO
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -79,9 +82,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         m_prev = m_scr[...]                                  # (BQ,)
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1))
         # fully-masked-so-far rows keep m == NEG_INF: no correction term
-        correction = jnp.where(m_prev == NEG_INF, 0.0,
+        correction = jnp.where(m_prev == NEG_INF, _FZERO,
                                jnp.exp(m_prev - m_new))
-        p = jnp.where(mask, jnp.exp(scores - m_new[:, None]), 0.0)
+        p = jnp.where(mask, jnp.exp(scores - m_new[:, None]), _FZERO)
         l_scr[...] = l_scr[...] * correction + jnp.sum(p, axis=1)
         acc_scr[...] = acc_scr[...] * correction[:, None] + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
@@ -120,6 +123,9 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         kt = jnp.pad(kt, ((0, 0), (0, 0), (0, pad), (0, 0)))
         vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pad), (0, 0)))
 
+    def kv_head(hh):                 # hh // groups as int32 (x64 would widen)
+        return jax.lax.div(hh, np.int32(groups))
+
     kernel = functools.partial(
         _kernel, scale=scale, block_q=block_q, block_k=block_k, seq_len=s,
         causal=causal, window=window, n_kv_blocks=n_k)
@@ -129,21 +135,21 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         grid=(b, h, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d),
-                         lambda bb, hh, qq, kk: (bb, hh, qq, 0)),
+                         lambda bb, hh, qq, kk: (bb, hh, qq, _ZERO)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda bb, hh, qq, kk: (bb, hh // groups, kk, 0)),
+                         lambda bb, hh, qq, kk: (bb, kv_head(hh), kk, _ZERO)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda bb, hh, qq, kk: (bb, hh // groups, kk, 0)),
+                         lambda bb, hh, qq, kk: (bb, kv_head(hh), kk, _ZERO)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d),
-                               lambda bb, hh, qq, kk: (bb, hh, qq, 0)),
+                               lambda bb, hh, qq, kk: (bb, hh, qq, _ZERO)),
         out_shape=jax.ShapeDtypeStruct((b, h, n_q * block_q, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -23,12 +23,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
-NEG_INF = -1e30
+# Constant block index for index maps: an int32 scalar, since a Python 0
+# traces as int64 under x64 and Mosaic rejects 64-bit block indices.
+_ZERO = np.int32(0)
+NEG_INF = np.float32(-1e30)
+_FZERO = np.float32(0.0)    # f32 even under x64, like _ZERO
 
 
 def _kernel(h_ref, w_ref, lab_ref, o_ref, m_scr, l_scr, lab_scr, *,
@@ -55,8 +58,8 @@ def _kernel(h_ref, w_ref, lab_ref, o_ref, m_scr, l_scr, lab_scr, *,
     # online logsumexp
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1))
-    corr = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
-    p = jnp.where(valid, jnp.exp(logits - m_new[:, None]), 0.0)
+    corr = jnp.where(m_prev == NEG_INF, _FZERO, jnp.exp(m_prev - m_new))
+    p = jnp.where(valid, jnp.exp(logits - m_new[:, None]), _FZERO)
     l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1)
     m_scr[...] = m_new
 
@@ -64,7 +67,7 @@ def _kernel(h_ref, w_ref, lab_ref, o_ref, m_scr, l_scr, lab_scr, *,
     lab = lab_ref[...]                            # (BT,)
     hit = (v_pos == lab[:, None]) & valid
     lab_scr[...] = lab_scr[...] + jnp.sum(
-        jnp.where(hit, logits, 0.0), axis=1)
+        jnp.where(hit, logits, _FZERO), axis=1)
 
     @pl.when(vi == n_v_blocks - 1)
     def finalize():
@@ -97,8 +100,8 @@ def fused_ce(hidden, w_vocab, labels, *, block_t: int = 128,
         kernel,
         grid=(n_t, n_v),
         in_specs=[
-            pl.BlockSpec((block_t, d), lambda ti, vi: (ti, 0)),
-            pl.BlockSpec((d, block_v), lambda ti, vi: (0, vi)),
+            pl.BlockSpec((block_t, d), lambda ti, vi: (ti, _ZERO)),
+            pl.BlockSpec((d, block_v), lambda ti, vi: (_ZERO, vi)),
             pl.BlockSpec((block_t,), lambda ti, vi: (ti,)),
         ],
         out_specs=pl.BlockSpec((block_t,), lambda ti, vi: (ti,)),
@@ -108,7 +111,7 @@ def fused_ce(hidden, w_vocab, labels, *, block_t: int = 128,
             pltpu.VMEM((block_t,), jnp.float32),
             pltpu.VMEM((block_t,), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(hidden, w_vocab, labels.astype(jnp.int32))

@@ -41,29 +41,59 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+# Constant block index for index maps: an int32 scalar, since a Python 0
+# traces as int64 under x64 and Mosaic rejects 64-bit block indices.
+_ZERO = np.int32(0)
+
+
+def _column(x, k):
+    """``x[:, k:k+1]`` for a traced ``k``, as a masked lane reduction.
+
+    Mosaic has no value-level dynamic slice; adding zeros to the one
+    selected entry keeps the result exact.
+    """
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.sum(jnp.where(lane == k, x, np.float32(0.0)), axis=1,
+                   keepdims=True)
+
+
+def _loop(n: int, body, init):
+    """``fori_loop(0, n, body, init)`` with an int32 index.
+
+    Under x64 ``fori_loop``'s index comes out int64, even from
+    ``np.int32`` bounds, and Mosaic cannot lower it.
+    """
+    def step(carry, _):
+        k, x = carry
+        return (k + 1, body(k, x)), None
+
+    (_, out), _ = jax.lax.scan(step, (np.int32(0), init), None, length=n)
+    return out
 
 
 def _pmf_body(p, cos, sin, size: int, n: int):
     """Shared DFT-pmf computation: (BB, N) fp32 probs -> (BB, S) pmf."""
-    omega_re = cos[1, :]                   # cos(2π n / S), n = 0..S-1
-    omega_im = sin[1, :]                   # sin(2π n / S)
+    omega_re = cos[1:2, :]                 # cos(2π n / S), n = 0..S-1
+    omega_im = sin[1:2, :]                 # sin(2π n / S)
 
     def chi_step(k, carry):
         re, im = carry                     # (BB, S) running complex product
-        pk = jax.lax.dynamic_slice_in_dim(p, k, 1, axis=1)   # (BB, 1)
-        t_re = pk * (omega_re[None, :] - 1.0) + 1.0
-        t_im = pk * omega_im[None, :]
+        pk = _column(p, k)                 # (BB, 1)
+        t_re = pk * (omega_re - 1.0) + 1.0
+        t_im = pk * omega_im
         return re * t_re - im * t_im, re * t_im + im * t_re
 
     ones = jnp.ones((p.shape[0], size), jnp.float32)
-    chi_re, chi_im = jax.lax.fori_loop(0, n, chi_step, (ones, ones * 0.0))
+    chi_re, chi_im = _loop(n, chi_step, (ones, ones * 0.0))
     # Re[Σ_n e^{-2πi nm/S} χ(n)] / S; cos/sin matrices are symmetric.
-    raw = (jnp.dot(chi_re, cos, preferred_element_type=jnp.float32)
-           + jnp.dot(chi_im, sin, preferred_element_type=jnp.float32)) / size
-    raw = jnp.clip(raw, 0.0, 1.0)
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
+    raw = (dot(chi_re, cos) + dot(chi_im, sin)) / size
+    raw = jnp.minimum(jnp.maximum(raw, 0.0), 1.0)    # clip, f32 bounds
     return raw / jnp.sum(raw, axis=1, keepdims=True)
 
 
@@ -79,28 +109,27 @@ def _kernel_loo(p_ref, cos_ref, sin_ref, pmf_ref, loo_ref, *, n: int):
 
     # Leave-one-out for all N nodes at once; (BB, S, N) output layout.
     use_fwd = p <= 0.5                                 # (BB, N)
-    q_safe = jnp.where(use_fwd, 1.0 - p, 0.5)          # benign divisors for
-    p_safe = jnp.where(use_fwd, 0.5, p)                # the masked-out branch
+    half = np.float32(0.5)
+    q_safe = jnp.where(use_fwd, 1.0 - p, half)         # benign divisors for
+    p_safe = jnp.where(use_fwd, half, p)               # the masked-out branch
     zero = jnp.zeros(p.shape, jnp.float32)
 
     def fwd_step(k, g_prev):
-        f_k = jax.lax.dynamic_slice_in_dim(f, k, 1, axis=1)       # (BB, 1)
-        g_k = (f_k - p * g_prev) / q_safe
+        g_k = (_column(f, k) - p * g_prev) / q_safe
         loo_ref[:, pl.ds(k, 1), :] = g_k[:, None, :]
         return g_k
 
-    jax.lax.fori_loop(0, n, fwd_step, zero)
+    _loop(n, fwd_step, zero)
     loo_ref[:, pl.ds(n, 1), :] = zero[:, None, :]      # support is 0..N-1
 
     def bwd_step(j, g_next):                           # k runs n-1 .. 0
         k = n - 1 - j
-        f_k1 = jax.lax.dynamic_slice_in_dim(f, k + 1, 1, axis=1)
-        g_k = (f_k1 - (1.0 - p) * g_next) / p_safe
+        g_k = (_column(f, k + 1) - (1.0 - p) * g_next) / p_safe
         keep = loo_ref[:, pl.ds(k, 1), :][:, 0, :]     # forward-pass value
         loo_ref[:, pl.ds(k, 1), :] = jnp.where(use_fwd, keep, g_k)[:, None, :]
         return g_k
 
-    jax.lax.fori_loop(0, n, bwd_step, zero)
+    _loop(n, bwd_step, zero)
 
 
 @functools.partial(jax.jit,
@@ -120,19 +149,19 @@ def poibin_dft(p_mat, *, block_b: int = 8, with_loo: bool = True,
     sin = jnp.sin(ang).astype(jnp.float32)
 
     in_specs = [
-        pl.BlockSpec((block_b, n), lambda i: (i, 0)),
-        pl.BlockSpec((size, size), lambda i: (0, 0)),
-        pl.BlockSpec((size, size), lambda i: (0, 0)),
+        pl.BlockSpec((block_b, n), lambda i: (i, _ZERO)),
+        pl.BlockSpec((size, size), lambda i: (_ZERO, _ZERO)),
+        pl.BlockSpec((size, size), lambda i: (_ZERO, _ZERO)),
     ]
     if not with_loo:
         pmf = pl.pallas_call(
             functools.partial(_kernel_pmf, n=n),
             grid=(n_b,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((block_b, size), lambda i: (i, 0)),
+            out_specs=pl.BlockSpec((block_b, size), lambda i: (i, _ZERO)),
             out_shape=jax.ShapeDtypeStruct((n_b * block_b, size),
                                            jnp.float32),
-            compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
             interpret=interpret,
         )(p32, cos, sin)
         return pmf[:b].astype(p_mat.dtype)
@@ -142,14 +171,14 @@ def poibin_dft(p_mat, *, block_b: int = 8, with_loo: bool = True,
         grid=(n_b,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((block_b, size), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, size, n), lambda i: (i, 0, 0)),
+            pl.BlockSpec((block_b, size), lambda i: (i, _ZERO)),
+            pl.BlockSpec((block_b, size, n), lambda i: (i, _ZERO, _ZERO)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_b * block_b, size), jnp.float32),
             jax.ShapeDtypeStruct((n_b * block_b, size, n), jnp.float32),
         ],
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(p32, cos, sin)
     return (pmf[:b].astype(p_mat.dtype),

@@ -1,6 +1,8 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose ground truth)."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -108,6 +110,7 @@ def fedavg_agg_ref(global_flat, client_flat, mask):
                      global_flat.astype(jnp.float32)).astype(global_flat.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("with_loo",))
 def poibin_dft_ref(p_mat, with_loo: bool = True):
     """Batched Poisson-Binomial DFT pmf + leave-one-out deconvolution.
 
@@ -115,24 +118,36 @@ def poibin_dft_ref(p_mat, with_loo: bool = True):
     ``with_loo`` — loo (B, N, N+1) where ``loo[b, i]`` is the pmf of
     scenario b's nodes excluding node i (support 0..N-1, last entry zero).
 
-    Same math as :func:`repro.core.poibin.poibin_pmf` (eq. (9) DFT with
-    clip + renormalize) and :func:`repro.core.poibin.poibin_pmf_loo`
-    (forward recursion for p ≤ 1/2, backward for p > 1/2), restated here
-    self-contained in the input dtype so the kernel layer stays
-    dependency-free; the three-way agreement (this oracle, the Pallas
-    kernel, the repro.core functions) is pinned in
-    ``tests/test_property_poibin.py``.
+    Same math as :func:`repro.core.poibin.poibin_pmf` (eq. (9) DFT in real
+    (re, im) arithmetic, with clip + renormalize) and
+    :func:`repro.core.poibin.poibin_pmf_loo` (forward recursion for
+    p ≤ 1/2, backward for p > 1/2), restated here self-contained in the
+    input dtype so the kernel layer stays dependency-free; the three-way
+    agreement (this oracle, the Pallas kernel, the repro.core functions) is
+    pinned in ``tests/test_property_poibin.py``.
     """
     p_mat = jnp.asarray(p_mat)
     _, n = p_mat.shape
     size = n + 1
-    cdtype = jnp.complex64 if p_mat.dtype == jnp.float32 else jnp.complex128
+    dtype = p_mat.dtype
     idx = jnp.arange(size)
-    omega = jnp.exp(2j * jnp.pi * idx / size).astype(cdtype)   # (S,)
-    terms = p_mat[:, None, :] * (omega[None, :, None] - 1.0) + 1.0
-    chi = jnp.prod(terms, axis=2)                              # (B, S)
-    dft = jnp.exp(-2j * jnp.pi * jnp.outer(idx, idx) / size).astype(cdtype)
-    raw = jnp.clip((chi @ dft.T).real / size, 0.0, 1.0)
+    ang = 2 * jnp.pi * idx / size
+    w_re, w_im = jnp.cos(ang).astype(dtype), jnp.sin(ang).astype(dtype)
+
+    def factor(chi, p_k):                                      # p_k: (B,)
+        re, im = chi
+        t_re = p_k[:, None] * (w_re - 1.0) + 1.0
+        t_im = p_k[:, None] * w_im
+        return (re * t_re - im * t_im, re * t_im + im * t_re), None
+
+    one = jnp.ones((p_mat.shape[0], size), dtype)
+    (chi_re, chi_im), _ = jax.lax.scan(factor, (one, jnp.zeros_like(one)),
+                                       p_mat.T)                # (B, S) each
+    theta = 2 * jnp.pi * jnp.outer(idx, idx) / size
+    cos, sin = jnp.cos(theta).astype(dtype), jnp.sin(theta).astype(dtype)
+    raw = jnp.sum(cos * chi_re[:, None, :] + sin * chi_im[:, None, :],
+                  axis=2) / size
+    raw = jnp.clip(raw, 0.0, 1.0)
     pmf = raw / jnp.sum(raw, axis=1, keepdims=True)
     if not with_loo:
         return pmf

@@ -23,10 +23,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+# Constant block index for index maps: an int32 scalar, since a Python 0
+# traces as int64 under x64 and Mosaic rejects 64-bit block indices.
+_ZERO = np.int32(0)
 
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_out_ref, state_scr,
@@ -89,22 +92,22 @@ def rwkv6_scan(r, k, v, w, u, *, block_t: int = 256, interpret: bool = False):
         kernel,
         grid=(b, h, n_t),
         in_specs=[
-            pl.BlockSpec((1, 1, block_t, d), lambda bb, hh, tt: (bb, hh, tt, 0)),
-            pl.BlockSpec((1, 1, block_t, d), lambda bb, hh, tt: (bb, hh, tt, 0)),
-            pl.BlockSpec((1, 1, block_t, d), lambda bb, hh, tt: (bb, hh, tt, 0)),
-            pl.BlockSpec((1, 1, block_t, d), lambda bb, hh, tt: (bb, hh, tt, 0)),
-            pl.BlockSpec((1, d), lambda bb, hh, tt: (hh, 0)),
+            pl.BlockSpec((1, 1, block_t, d), lambda bb, hh, tt: (bb, hh, tt, _ZERO)),
+            pl.BlockSpec((1, 1, block_t, d), lambda bb, hh, tt: (bb, hh, tt, _ZERO)),
+            pl.BlockSpec((1, 1, block_t, d), lambda bb, hh, tt: (bb, hh, tt, _ZERO)),
+            pl.BlockSpec((1, 1, block_t, d), lambda bb, hh, tt: (bb, hh, tt, _ZERO)),
+            pl.BlockSpec((1, d), lambda bb, hh, tt: (hh, _ZERO)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_t, d), lambda bb, hh, tt: (bb, hh, tt, 0)),
-            pl.BlockSpec((1, 1, d, d), lambda bb, hh, tt: (bb, hh, 0, 0)),
+            pl.BlockSpec((1, 1, block_t, d), lambda bb, hh, tt: (bb, hh, tt, _ZERO)),
+            pl.BlockSpec((1, 1, d, d), lambda bb, hh, tt: (bb, hh, _ZERO, _ZERO)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, n_t * block_t, d), r.dtype),
             jax.ShapeDtypeStruct((b, h, d, d), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(rt, kt, vt, wt, u)

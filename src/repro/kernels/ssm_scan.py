@@ -20,10 +20,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+# Constant block index for index maps: an int32 scalar, since a Python 0
+# traces as int64 under x64 and Mosaic rejects 64-bit block indices.
+_ZERO = np.int32(0)
 
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, dskip_ref, y_ref, h_out_ref,
@@ -100,22 +103,22 @@ def ssm_scan(x, delta, a_log, b, c, d_skip, *, block_t: int = 256,
                          lambda bb, dd, tt: (bb, tt, dd)),
             pl.BlockSpec((1, block_t, block_d),
                          lambda bb, dd, tt: (bb, tt, dd)),
-            pl.BlockSpec((block_d, n), lambda bb, dd, tt: (dd, 0)),
-            pl.BlockSpec((1, block_t, n), lambda bb, dd, tt: (bb, tt, 0)),
-            pl.BlockSpec((1, block_t, n), lambda bb, dd, tt: (bb, tt, 0)),
+            pl.BlockSpec((block_d, n), lambda bb, dd, tt: (dd, _ZERO)),
+            pl.BlockSpec((1, block_t, n), lambda bb, dd, tt: (bb, tt, _ZERO)),
+            pl.BlockSpec((1, block_t, n), lambda bb, dd, tt: (bb, tt, _ZERO)),
             pl.BlockSpec((block_d,), lambda bb, dd, tt: (dd,)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_t, block_d),
                          lambda bb, dd, tt: (bb, tt, dd)),
-            pl.BlockSpec((1, block_d, n), lambda bb, dd, tt: (bb, dd, 0)),
+            pl.BlockSpec((1, block_d, n), lambda bb, dd, tt: (bb, dd, _ZERO)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bsz, n_t * block_t, n_d * block_d), x.dtype),
             jax.ShapeDtypeStruct((bsz, n_d * block_d, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_d, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, delta, a_log, b, c, d_skip)

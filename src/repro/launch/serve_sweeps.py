@@ -18,6 +18,7 @@ import pathlib
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import EventSink
 from repro.serve import SweepService
 from repro.serve.workload import synthetic_workload
@@ -55,6 +56,7 @@ def main(argv: list[str] | None = None) -> int:
     else:
         ap.error("one of --input or --demo is required")
 
+    enable_compile_cache()
     sink = None
     if args.events:
         # the sink appends; the driver owns the file, so start it fresh

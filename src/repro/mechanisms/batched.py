@@ -31,11 +31,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.scipy.special import gammaln, xlog1py, xlogy
+from jax.scipy.special import xlog1py, xlogy
 
 from repro.core.duration import DurationModel
 from repro.core.game import P_MAX, P_MIN
@@ -60,9 +61,18 @@ def binom_pmf(p: jax.Array, n: int) -> jax.Array:
     Shape: ``p (...,) -> (..., n+1)``.
     """
     k = jnp.arange(n + 1, dtype=p.dtype)
-    log_comb = (gammaln(n + 1.0) - gammaln(k + 1.0) - gammaln(n - k + 1.0))
+    log_comb = jnp.asarray(_log_comb(n), p.dtype)
     log_pmf = log_comb + xlogy(k, p[..., None]) + xlog1py(n - k, -p[..., None])
     return jnp.exp(log_pmf)
+
+
+@functools.lru_cache(maxsize=None)
+def _log_comb(n: int) -> np.ndarray:
+    """``log C(n, k)`` for k = 0..n, on the host: ``n`` is static, and an
+    f64 ``gammaln`` in the program costs ~18 s of TPU compile per use."""
+    out = np.array([math.log(math.comb(n, k)) for k in range(n + 1)])
+    out.setflags(write=False)       # cached: shared by every caller
+    return out
 
 
 def batched_phi(

@@ -1,7 +1,8 @@
 """ResNet-18 in pure JAX — the paper's federated workload (CIFAR-10).
 
-11.18M parameters at width 64 and 10 classes, matching Table I
-(w = 11 181 642, S_w = 44.73 MB fp32). Norm layer is configurable:
+11,173,962 parameters at width 64 and 10 classes with the CIFAR 3×3
+stem. Table I's w = 11 181 642 (S_w = 44.73 MB fp32) is the same network
+with torchvision's 7×7 stem, 7,680 more weights. Norm layer is configurable:
 ``groupnorm`` (default — BN running stats are notoriously ill-posed under
 FedAvg) or ``batchnorm`` (paper-faithful; stats are FedAvg-merged like any
 other parameter). See DESIGN.md §9.

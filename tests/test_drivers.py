@@ -70,3 +70,28 @@ def test_serve_sweeps_driver_demo(tmp_path):
               for line in events_path.read_text().splitlines()]
     assert sum(e["event"] == "serve.request" for e in events) == 6
     assert sum(e["event"] == "serve.complete" for e in events) == 6
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_location(tmp_path, from_env):
+    """``enable_compile_cache`` keeps JAX's persistent cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says (and sets no other path), else in
+    the checkout's fixed ``.jax_cache/``."""
+    env = dict(ENV, JAX_PLATFORMS="cpu",
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    code = ("import jax\n"
+            "from repro.launch.compile_cache import enable_compile_cache\n"
+            "print(enable_compile_cache())\n"
+            "print(jax.config.jax_compilation_cache_dir)\n"
+            + ("jax.jit(lambda x: x + 1)(1.0).block_until_ready()\n"
+               if from_env else ""))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=120, cwd=tmp_path)
+    assert out.returncode == 0, out.stderr[-2000:]
+    want = str(tmp_path) if from_env else os.path.join(REPO, ".jax_cache")
+    assert out.stdout.split() == [want, want]
+    if from_env:
+        assert os.listdir(tmp_path), "the compile was not cached there"

@@ -307,15 +307,14 @@ def test_dispatch_counts_once_under_shard_map():
     device the process has (8 in the multi-device CI job)."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.federated.distributed import _shard_map
-
     mesh = Mesh(np.array(jax.devices()), ("data",))
 
     def body(x):
         ops.resolve_backend(None, default="ref", site="test.shard_map_site")
         return x * 2
 
-    fn = _shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P("data"))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+                       check_vma=False)
     ops.reset_dispatch_stats()
     x = jnp.arange(jax.device_count() * 2.0)
     jax.block_until_ready(jax.jit(fn)(x))
