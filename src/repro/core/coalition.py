@@ -37,9 +37,9 @@ Everything is written single-scenario and ``vmap``-ed over
 (costs, gammas, cap) batches in the jitted wrappers.
 
 Oracle-first rails: :func:`partition_equilibrium_reference` restates both
-levels as eager Python loops over *compact* subgames (no masks — each
-coalition's pmf is built from its members only), kept verbatim as the test
-oracle for ``tests/test_property_coalition.py``.
+levels as plain Python loops over *compact* subgames (no masks — each
+coalition's pmf is built from its members only), kept as the test oracle
+for ``tests/test_property_coalition.py``.
 """
 from __future__ import annotations
 
@@ -558,16 +558,33 @@ def partition_poa_report(
 
 
 # ---------------------------------------------------------------------------
-# Python reference oracle (kept verbatim; tests/test_property_coalition.py)
+# Python reference oracle (tests/test_property_coalition.py)
 # ---------------------------------------------------------------------------
+
+@jax.jit
+def _reference_best_response(others, d_tab, cost, gamma):
+    """Node's best response to the compact pmf of its coalition's others."""
+    pmf = poibin_pmf_recursive(others)                 # (|S|,) support
+    k = pmf.shape[0]
+    slope = -(pmf @ (d_tab[1:k + 1] - d_tab[:k]))
+    return best_response_given_slope(slope, cost, gamma)
+
+
+@jax.jit
+def _reference_node_utility(probs, d_tab, cost, gamma, p_i):
+    """u_i from the compact pmf of all of the coalition's ``probs``."""
+    pmf = poibin_pmf_recursive(probs)
+    e_d = pmf @ d_tab[:pmf.shape[0]]
+    return -e_d - gamma * log_aoi(p_i) - cost * p_i
+
 
 def _reference_subgame_ne(costs, gammas, d_tab, members, *, damping,
                           max_iters, tol):
-    """Eager compact-subgame Gauss-Seidel: the simplest statement of the
-    inner NE — pmfs are built from the coalition's members only (no
-    masks), matching the engine's fixed points to solver tolerance."""
-    import numpy as np
-
+    """Compact-subgame Gauss-Seidel: the simplest statement of the inner
+    NE — pmfs are built from the coalition's members only (no masks),
+    matching the engine's fixed points to solver tolerance. The loop is
+    plain Python; each node step is one compiled call, retraced once per
+    coalition size."""
     members = list(members)
     p = {i: 0.5 for i in members}
     for _ in range(max_iters):
@@ -575,13 +592,8 @@ def _reference_subgame_ne(costs, gammas, d_tab, members, *, damping,
         for i in members:
             others = jnp.asarray([p[j] for j in members if j != i],
                                  jnp.float64)
-            pmf = np.asarray(poibin_pmf_recursive(others))   # (|S|,) support
-            k = pmf.shape[0]
-            dd = np.asarray(d_tab[1:k + 1]) - np.asarray(d_tab[:k])
-            slope = -float(pmf @ dd)
-            br = float(best_response_given_slope(
-                jnp.asarray(slope), jnp.asarray(float(costs[i])),
-                jnp.asarray(float(gammas[i]))))
+            br = float(_reference_best_response(others, d_tab, costs[i],
+                                                gammas[i]))
             new_pi = (1.0 - damping) * p[i] + damping * br
             delta = max(delta, abs(new_pi - p[i]))
             p[i] = new_pi
@@ -592,13 +604,9 @@ def _reference_subgame_ne(costs, gammas, d_tab, members, *, damping,
 
 def _reference_utility(costs, gammas, d_tab, members, p, i):
     """u_i at the compact subgame profile ``p`` (dict over ``members``)."""
-    import numpy as np
-
     probs = jnp.asarray([p[j] for j in members], jnp.float64)
-    pmf = np.asarray(poibin_pmf_recursive(probs))
-    e_d = float(pmf @ np.asarray(d_tab[:pmf.shape[0]]))
-    return (-e_d - float(gammas[i]) * float(log_aoi(jnp.asarray(p[i])))
-            - float(costs[i]) * p[i])
+    return float(_reference_node_utility(probs, d_tab, costs[i], gammas[i],
+                                         p[i]))
 
 
 def partition_equilibrium_reference(
@@ -615,11 +623,13 @@ def partition_equilibrium_reference(
     switch_tol: float = 1e-6,
     max_switches: int | None = None,
 ):
-    """Eager Python restatement of :func:`solve_partition` (the oracle).
+    """Plain-loop Python restatement of :func:`solve_partition` (the oracle).
 
     Both levels as plain loops over *compact* subgames: inner NEs are
     solved on each coalition's members only (list-of-indices, no masked
-    fleet-width arrays), the outer loop re-solves every
+    fleet-width arrays), each swept in node-index order as the engine's
+    masked sweep is (where a subgame has several NEs, the order selects
+    one), and the outer loop re-solves every
     (node, coalition) candidate and applies the single best eligible
     switch — the same best-switch-first tie-breaking (row-major argmax
     over the (N, M) gain table) as the engine. Returns
@@ -629,8 +639,8 @@ def partition_equilibrium_reference(
     """
     import numpy as np
 
-    d_tab = np.asarray(dur.table() if isinstance(dur, DurationModel)
-                       else jnp.asarray(dur))
+    d_tab = jnp.asarray(dur.table() if isinstance(dur, DurationModel)
+                        else dur)
     costs = np.asarray(costs, np.float64)
     gammas = np.asarray(gammas, np.float64)
     n = costs.shape[0]
@@ -666,7 +676,7 @@ def partition_equilibrium_reference(
             for c in range(m):
                 if c == c0 or sizes[c] >= cap:
                     continue
-                joined = coalition_members(assign, c) + [i]
+                joined = sorted(coalition_members(assign, c) + [i])
                 p_cand = _reference_subgame_ne(
                     costs, gammas, d_tab, joined, damping=damping,
                     max_iters=max_iters, tol=tol)

@@ -1,33 +1,17 @@
 """Shared pytest configuration.
 
-Registers deterministic hypothesis profiles so the property suite behaves
-the same on every CI run:
-
-* ``ci`` — derandomized (fixed example database-free seed), CI-sized
-  ``max_examples``, no deadline (JAX compile times would trip it). Loaded
-  automatically when ``$CI`` is set; CI also pins it explicitly via
-  ``HYPOTHESIS_PROFILE=ci``.
-* ``dev`` — the local default: random seeds, same deadline settings.
-
-Note: per-test ``@settings(...)`` decorators override only the keys they
-set; ``derandomize`` comes from the active profile either way.
+Registers and loads one Hypothesis profile, whatever the environment:
+derandomized, so every run (local, CI, tier-1) draws the same examples and
+the example database is off, and with no deadline, since JAX compile times
+would trip it. Each property sets its own ``max_examples``.
 """
 from __future__ import annotations
-
-import os
 
 try:
     from hypothesis import settings
 except ImportError:  # hypothesis is an optional test dep (importorskip)
     pass
 else:
-    settings.register_profile("ci", max_examples=25, derandomize=True,
-                              deadline=None, print_blob=True)
-    settings.register_profile("dev", deadline=None)
-    _profile = os.environ.get("HYPOTHESIS_PROFILE")
-    if _profile:
-        settings.load_profile(_profile)
-    elif os.environ.get("CI"):
-        settings.load_profile("ci")
-    else:
-        settings.load_profile("dev")
+    settings.register_profile("repro", derandomize=True, deadline=None,
+                              print_blob=True)
+    settings.load_profile("repro")

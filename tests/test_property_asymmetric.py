@@ -8,14 +8,16 @@ Invariants pinned here on random games:
   (max profitable unilateral deviation ≤ 1e-4);
 * identical-node batches reproduce the symmetric ``solve_symmetric_ne``
   equilibrium;
-* participation is weakly decreasing in cost (free-rider stratification).
+* participation is weakly decreasing in cost, except across a pair of
+  nodes whose own participation gap sustains the inversion (free-rider
+  stratification).
 """
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 pytest.importorskip("hypothesis")  # optional dep: skip, don't die, without it
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import example, given, settings, strategies as st  # noqa: E402
 
 import repro.core as C
 from repro.core.asymmetric import (HeterogeneousGame,
@@ -23,6 +25,7 @@ from repro.core.asymmetric import (HeterogeneousGame,
 from repro.core.asymmetric_batched import (solve_heterogeneous,
                                            verify_equilibrium_batched)
 from repro.core.game import solve_symmetric_ne
+from repro.core.poibin import poibin_pmf_recursive
 from repro.core.utility import UtilityParams
 from helpers import assert_heterogeneous_ne
 
@@ -92,15 +95,35 @@ def test_identical_nodes_reproduce_symmetric_ne(gamma, cost):
 
 @settings(max_examples=10, deadline=None)
 @given(st.floats(0.1, 1.0), st.floats(1.0, 12.0), seeds)
+@example(gamma=1.0, cost_hi=1.0, seed=0)
 def test_participation_weakly_decreasing_in_cost(gamma, cost_hi, seed):
+    """A cheaper node participates at least as much as a costlier one,
+    unless the pair sustains stratification. With equal γ the best
+    response rises in a = slope - cost, so p_i < p_j at c_i < c_j needs
+    slope_j - slope_i > c_j - c_i; and exactly
+    slope_j - slope_i = (p_j - p_i)·E[Δ²d(m_{-ij})], m_{-ij} counting
+    the other nodes. seed=0 settles on such a certified NE: two nodes
+    0.08 apart in cost at p ≈ 0.13 and p = 1
+    (``test_identical_nodes_can_stratify`` is the equal-cost case). The
+    solve runs at tol=1e-10: at the default tol, nodes at the p = 1 corner
+    stop up to ~tol/damping short of it, apart by more than 1e-6."""
     n = 8
     rng = np.random.default_rng(seed)
     dur = _dur(n)
     costs = jnp.asarray(np.sort(rng.uniform(0.1, cost_hi, n)))
     gammas = jnp.full((n,), gamma)
-    sol = solve_heterogeneous(costs, gammas, dur, damping=0.6, max_iters=300)
+    sol = solve_heterogeneous(costs, gammas, dur, damping=0.6, max_iters=300,
+                              tol=1e-10)
     p, conv, _ = sol.single()
     if not conv:
         return
-    assert bool(jnp.all(jnp.diff(p) <= 1e-6)), np.asarray(p)
     assert_heterogeneous_ne(costs, gammas, dur, p)
+    p, costs = np.asarray(p), np.asarray(costs)
+    d = np.asarray(dur.table())
+    d2 = d[2:] - 2.0 * d[1:-1] + d[:-2]                # Δ²d(k), k = 0..n-2
+    inverted = np.triu(p[None, :] - p[:, None] > 1e-6, k=1)  # i < j
+    for i, j in np.argwhere(inverted):
+        rest = jnp.asarray(np.delete(p, [i, j]))
+        pmf = np.asarray(poibin_pmf_recursive(rest))
+        sustained = (p[j] - p[i]) * float(pmf @ d2)
+        assert costs[j] - costs[i] < sustained, (i, j, p, sustained)

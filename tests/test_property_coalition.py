@@ -2,7 +2,7 @@
 
 Invariants pinned here on random games:
 
-* the jitted partition dynamics (``solve_partition``) reproduce the eager
+* the jitted partition dynamics (``solve_partition``) reproduce the plain-loop
   Python oracle (``partition_equilibrium_reference``) on small fleets —
   same assignment, matching participation profiles;
 * the grand-coalition configuration (M = 1) reduces **bitwise** to the
@@ -22,7 +22,7 @@ import numpy as np
 import pytest
 
 pytest.importorskip("hypothesis")  # optional dep: skip, don't die, without it
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import example, given, settings, strategies as st  # noqa: E402
 
 import repro.core as C
 from repro.core.asymmetric_batched import solve_heterogeneous
@@ -47,9 +47,12 @@ def _fleet(rng, n, b=None):
 
 @settings(max_examples=4, deadline=None)
 @given(st.integers(3, 4), seeds)
+@example(n=4, seed=14118)
 def test_engine_matches_python_oracle(n, seed):
-    """Tier-1 smoke diff on tiny fleets — the eager oracle costs tens of
-    seconds per game, so bigger fleets live in the ``slow`` variant."""
+    """Tier-1 smoke diff on tiny fleets; bigger fleets live in the
+    ``slow`` variant. seed=14118 once failed here: the oracle swept a
+    joined candidate coalition with the joiner last, so it picked another
+    of the subgame's NEs than the engine's node-index sweep."""
     m = 2
     rng = np.random.default_rng(seed)
     dur = _dur(n)
@@ -127,10 +130,11 @@ def test_singleton_partition_monotone_as_gamma_shrinks(g_lo, g_hi, seed):
 @pytest.mark.slow
 @settings(max_examples=6, deadline=None)
 @given(st.integers(4, 6), st.integers(2, 3), st.integers(1, 3), seeds)
+@example(n=6, m=3, cap_slack=3, seed=235)
 def test_engine_matches_oracle_with_caps_slow(n, m, cap_slack, seed):
     """Nightly: bigger fleets, capped slots, full oracle diff. The oracle
-    runs at the default tolerance (it is eager Python — a tight tol costs
-    minutes per game); certification re-solves at tol=1e-10, where the
+    runs at the default tolerance (it is a Python loop — a tight tol
+    multiplies its sweeps); certification re-solves at tol=1e-10, where the
     corner residual ``tol/damping`` amplified by the boundary utility
     slope stays well under the 1e-6 budget."""
     rng = np.random.default_rng(seed)
