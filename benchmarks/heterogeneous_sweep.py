@@ -12,8 +12,8 @@ B-scenario (costs, gammas) sweep at N=50:
   extrapolated (the full loop takes hours); pass ``--full-scalar`` for an
   exact number.
 * ``batched`` — ``repro.core.asymmetric_batched.solve_heterogeneous``: the
-  same damped Gauss-Seidel semantics as one vmapped jitted XLA program
-  (leave-one-out pmf deconvolution instead of per-node recomputes).
+  same damped Gauss-Seidel semantics as one batched jitted XLA program
+  (an O(N) leave-one-out step instead of per-node recomputes).
 
 Every batched NE is certified by the jitted ``verify_equilibrium_batched``
 (max profitable unilateral deviation ≤ 1e-4) before the speedup is reported.

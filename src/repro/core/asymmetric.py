@@ -14,11 +14,12 @@ and optionally individual AoI weights ``gamma_i``. We compute:
 
 The heavy lifting lives in :mod:`repro.core.asymmetric_batched`: one jitted
 XLA program runs the damped Gauss-Seidel sweep as a `lax.scan` over nodes
-with O(N) leave-one-out pmf deconvolution, and ``vmap``s over scenario
-batches. :func:`best_response_dynamics`, :func:`verify_equilibrium`, and
-:func:`planner_coordinate_descent` below keep their original signatures and
-semantics but delegate there (B = 1); the pre-batching Python-loop
-implementations are retained as ``*_reference`` oracles for tests.
+with an O(N) leave-one-out step on the fleet's characteristic function,
+over a batch of scenarios. :func:`best_response_dynamics`,
+:func:`verify_equilibrium`, and :func:`planner_coordinate_descent` below
+keep their original signatures and semantics but delegate there (B = 1);
+the pre-batching Python-loop implementations are retained as
+``*_reference`` oracles for tests.
 
 Everything reuses :mod:`repro.core.poibin`; the per-node best response
 exploits the same decomposition as the symmetric case: with opponents'

@@ -4,20 +4,21 @@ The scalar solver in :mod:`repro.core.asymmetric` runs Python-loop
 Gauss-Seidel with a full ``jnp.delete`` + O(N·N) DFT pmf recompute per node
 per iteration — seconds for a single N=50 equilibrium, and a (costs, gammas)
 scenario sweep is out of reach. This module rebuilds that layer as pure
-fixed-shape ``lax`` control flow, jitted once and ``vmap``-ed over a batch of
-scenarios:
+fixed-shape ``lax`` control flow, jitted once over a batch of scenarios:
 
-* **Damped Gauss-Seidel as a scan.** One round-robin sweep is a `lax.scan`
-  over nodes carrying ``(pmf, p)``: node i's opponents' pmf comes from the
-  O(N) leave-one-out *deconvolution* (:func:`repro.core.poibin.poibin_pmf_loo`
-  divides node i's ``[1-p_i, p_i]`` factor back out of the full pmf), its
-  exact closed-form best response is evaluated, and the updated factor is
-  convolved back in O(N). A full sweep is O(N²) — the same cost as *one*
-  pmf recompute in the scalar path — and the pmf is rebuilt from scratch
-  via the stable O(N²) convolution recursion once per sweep so
-  deconvolve/convolve round-trip error never accumulates across sweeps.
-  Sweeps iterate inside a `lax.while_loop` until the sweep-wide update
-  delta drops below ``tol`` (identical semantics to the scalar loop).
+* **Damped Gauss-Seidel as a scan, in the frequency domain.** One
+  round-robin sweep is a `lax.scan` over nodes, batched by hand with the
+  fleets on the last axis. Node i's opponents' characteristic function
+  (paper eq. 9's product, :mod:`repro.core.poibin`) is the running product
+  of the factors already updated this sweep times the suffix product of
+  the rest, which is rebuilt from the profile at the start of every sweep:
+  products only, nothing divided out, no error carried across sweeps. The
+  node's duration slope is one weighted sum over the spectrum's points,
+  its exact closed-form best response is evaluated and its new factor is
+  folded into the running product: O(N) elementwise work a node step, no
+  inner loop. Sweeps iterate inside a `lax.while_loop` until the
+  sweep-wide update delta drops below ``tol`` (identical semantics to the
+  scalar loop), each fleet stopping at its own sweep.
 * **Jitted certification.** :func:`verify_equilibrium_batched` evaluates
   every node's utility on a deviation grid in one shot — all N leave-one-out
   pmfs (a vmapped deconvolution), then a broadcast (N, G) utility table —
@@ -26,14 +27,15 @@ scenarios:
   each ``p_i`` with the others fixed (E[D] is multilinear), so the
   per-coordinate minimum sits at a corner determined by the sign of
   ``N·∂E[D]/∂p_i + c_i``; :func:`planner_batched` runs that coordinate
-  descent with the same deconvolution trick and matches the scalar
+  descent with the certifier's deconvolution and matches the scalar
   grid-argmin planner's fixed points.
 * **Heterogeneous PoA.** :func:`poa_report` packages NE + certification +
   planner + social costs for a whole scenario batch.
 
-Everything is written single-scenario and lifted with ``vmap`` in the jitted
-wrappers, so a ≥500-scenario (costs, gammas, dur) sweep at N=50 is one XLA
-dispatch (see ``benchmarks/heterogeneous_sweep.py``).
+The solve is written for the batch; everything else is written
+single-scenario and lifted with ``vmap`` in the jitted wrappers, so a
+≥500-scenario (costs, gammas, dur) sweep at N=50 is one XLA dispatch (see
+``benchmarks/heterogeneous_sweep.py``).
 
 The batch-parallel surfaces (:func:`verify_equilibrium_batched`,
 :func:`social_cost_batched`, and :func:`poa_report` through them) also
@@ -42,9 +44,8 @@ dispatch their Poisson-binomial work through the kernel layer: pass
 deconvolutions in the fused :mod:`repro.kernels.poibin_dft` kernel (fp32,
 parity to ~1e-6). The default ``"ref"`` keeps the pre-existing vmapped jnp
 programs bitwise-unchanged. The Gauss-Seidel NE solve itself stays jnp:
-its per-node sweep is sequential (each deconvolution uses the profile
-updated by the previous node), which is not the kernel's batch-parallel
-shape.
+its per-node sweep is sequential (each node step uses the profile updated
+by the previous node), which is not the kernel's batch-parallel shape.
 """
 from __future__ import annotations
 
@@ -56,9 +57,11 @@ import jax.numpy as jnp
 
 from repro.core.aoi import log_aoi
 from repro.core.duration import DurationModel
-from repro.core.poibin import (poibin_convolve, poibin_pmf_batched,
-                               poibin_pmf_loo, poibin_pmf_loo_all,
-                               poibin_pmf_recursive)
+from repro.core.poibin import (chi_size, poibin_chi_fold,
+                               poibin_chi_suffixes, poibin_convolve,
+                               poibin_pmf_batched, poibin_pmf_loo,
+                               poibin_pmf_loo_all, poibin_pmf_recursive,
+                               unit_roots)
 
 __all__ = [
     "P_MIN",
@@ -109,45 +112,85 @@ def best_response_given_slope(slope: jax.Array, cost: jax.Array,
 # Gauss-Seidel fixed point (single scenario; vmapped by the public wrapper)
 # ---------------------------------------------------------------------------
 
-def _gs_fixed_point(costs, gammas, d_tab, p0, *, damping, max_iters, tol):
-    n = costs.shape[0]
-    dd = d_tab[1:] - d_tab[:-1]
+def _gs_fixed_point(costs, gammas, d_tab, p0, *, damping, max_iters, tol,
+                    member=None):
+    """Damped Gauss-Seidel NEs of a ``(B, N)`` batch of fleets.
+
+    Runs in the frequency domain, on the first ``size // 2 + 1`` points of
+    each fleet's characteristic function (``size = chi_size(N)``; the other
+    points are their conjugates), with the fleets on the last axis. Node
+    i's opponents are the nodes before it at their new values and the
+    nodes after it at the sweep's old ones, so their characteristic
+    function is the running product ``pre`` of the new factors times the
+    suffix product of the old ones. The suffixes are rebuilt from the
+    profile at the start of every sweep: nothing is divided out and no
+    error carries from sweep to sweep. They start from the weighted DFT of
+    the duration increments, so node i's slope ``-sum_m g[m] dd[m]`` (g
+    the others' pmf) is one sum over the points. A node step is that sum,
+    the closed-form best response and one factor folded into ``pre``.
+
+    A fleet stops at its own sweep count, as under a vmapped while loop.
+    ``member`` (``(B, N)`` bool) pins the nodes outside it at ``p = 0``,
+    whose factor is exactly 1: the subgame on ``member`` at width N (the
+    coalition engine). ``None`` leaves every node free.
+    """
+    b, n = costs.shape
+    size = chi_size(n)
+    half = size // 2 + 1
+    w_re, w_im = unit_roots(size)
+    w = (w_re[:half, None], w_im[:half, None])
+    # -sum_m g[m] dd[m] = sum_k Re(chi_loo[k] c[k]) with c = -wt D: D the
+    # DFT of dd zero-padded to size, wt 1/size at k = 0 and 2/size at each
+    # conjugate pair. Sums, not dots: the TPU emulates a float64 dot with
+    # nested loops.
+    k = jnp.arange(half)
+    ang = 2 * jnp.pi * ((k[:, None] * jnp.arange(n)[None, :]) % size) / size
+    wt = jnp.where(k == 0, 1.0, 2.0)[:, None] / size
+    dd = (d_tab[:, 1:] - d_tab[:, :-1]).T                        # (N, B)
+    c_re = -wt * jnp.sum(jnp.cos(ang)[:, :, None] * dd, axis=1)  # (half, B)
+    c_im = wt * jnp.sum(jnp.sin(ang)[:, :, None] * dd, axis=1)
+    xs = (costs.T, gammas.T) + (() if member is None else (member.T,))
+    one = jnp.ones((half, b), d_tab.dtype)
+
+    def node(pre, x):
+        t_re, t_im, pi, cost, gamma, *keep = x
+        slope = jnp.sum(pre[0] * t_re - pre[1] * t_im, axis=0)  # utility side
+        br = best_response_given_slope(slope, cost, gamma)
+        new_pi = (1.0 - damping) * pi + damping * br
+        if keep:
+            new_pi = jnp.where(keep[0], new_pi, 0.0)
+        return poibin_chi_fold(pre, new_pi, w), new_pi
 
     def sweep(p):
-        f = poibin_pmf_recursive(p)  # fresh O(N²) pmf once per sweep
+        t = poibin_chi_suffixes(p, w, (c_re, c_im))
+        _, p_new = jax.lax.scan(node, (one, jnp.zeros_like(one)),
+                                t + (p,) + xs)
+        return p_new, jnp.max(jnp.abs(p_new - p), axis=0)
 
-        def node(carry, i):
-            f, p = carry
-            pi = p[i]
-            loo = poibin_pmf_loo(f, pi)              # (N+1,), last entry 0
-            slope = -(loo[:-1] @ dd)                  # utility-side slope
-            br = best_response_given_slope(slope, costs[i], gammas[i])
-            new_pi = (1.0 - damping) * pi + damping * br
-            f_new = poibin_convolve(loo, new_pi)
-            return (f_new, p.at[i].set(new_pi)), jnp.abs(new_pi - pi)
-
-        (_, p_new), deltas = jax.lax.scan(node, (f, p), jnp.arange(n))
-        return p_new, jnp.max(deltas)
+    def live(delta, it):
+        return (delta >= tol) & (it < max_iters)
 
     def cond(state):
         _, delta, it = state
-        return (delta >= tol) & (it < max_iters)
+        return jnp.any(live(delta, it))
 
     def body(state):
-        p, _, it = state
-        p_new, delta = sweep(p)
-        return p_new, delta, it + 1
+        p, delta, it = state
+        on = live(delta, it)
+        p_new, delta_new = sweep(p)
+        return (jnp.where(on, p_new, p), jnp.where(on, delta_new, delta),
+                it + on)
 
     p, delta, iters = jax.lax.while_loop(
-        cond, body, (p0, jnp.asarray(jnp.inf, p0.dtype), jnp.asarray(0)))
-    return p, delta < tol, iters
+        cond, body, (p0.T, jnp.full((b,), jnp.inf, p0.dtype),
+                     jnp.zeros((b,), int)))
+    return p.T, delta < tol, iters
 
 
 @functools.partial(jax.jit, static_argnames=("damping", "max_iters", "tol"))
 def _solve_vmapped(costs, gammas, d_tab, p0, *, damping, max_iters, tol):
-    solve = functools.partial(_gs_fixed_point, damping=damping,
-                              max_iters=max_iters, tol=tol)
-    return jax.vmap(solve)(costs, gammas, d_tab, p0)
+    return _gs_fixed_point(costs, gammas, d_tab, p0, damping=damping,
+                           max_iters=max_iters, tol=tol)
 
 
 # ---------------------------------------------------------------------------
@@ -306,7 +349,7 @@ def solve_heterogeneous(
             solve = functools.partial(
                 _gs_fixed_point, damping=statics[0],
                 max_iters=int(max_iters), tol=statics[2])
-            return jax.jit(jax.vmap(solve), in_shardings=sharding,
+            return jax.jit(solve, in_shardings=sharding,
                            out_shardings=sharding)
 
         prog = _sharded_program(("solve", mesh, batch_axis) + statics, build)

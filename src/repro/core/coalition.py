@@ -11,17 +11,18 @@ pooling (Yi et al., arXiv:2503.09039). The two-level game:
   ``u_i = -E[D_S] - γ_i·log E[Δ_i] - c_i·p_i``, eqs. 8-11 restricted to
   ``S``); its equilibrium is the certified asymmetric NE of
   :mod:`repro.core.asymmetric_batched`, solved here by the *same* damped
-  Gauss-Seidel sweep run masked: non-members are pinned at ``p = 0``
-  exactly, whose Bernoulli factor ``[1, 0]`` is a convolution identity, so
-  an all-true mask reproduces :func:`~repro.core.asymmetric_batched.
-  solve_heterogeneous` bitwise (the grand-coalition reduction pinned in
+  Gauss-Seidel sweep run masked (``_gs_fixed_point(..., member=)``):
+  non-members are pinned at ``p = 0`` exactly, whose Bernoulli factor is
+  exactly 1 at every frequency, so an all-true mask reproduces
+  :func:`~repro.core.asymmetric_batched.solve_heterogeneous` bitwise (the
+  grand-coalition reduction pinned in
   ``tests/test_property_coalition.py``).
 * **Outer game** — a hedonic partition game: node ``i`` in coalition
   ``S_c`` values a switch to ``S_{c'}`` at the utility it would earn at
   the *re-solved* inner NE of ``S_{c'} ∪ {i}`` (preferences depend only on
   the coalition joined — a hedonic game). :func:`solve_partition` runs
   jitted best-switch dynamics: per iteration every (node, coalition)
-  candidate NE is solved in one vmapped program, the single most
+  candidate NE is solved in one batched program, the single most
   profitable eligible switch (respecting the per-coalition cap) is
   applied, and the dynamics stop when no node gains more than
   ``switch_tol`` — a partition (Nash-stable hedonic) equilibrium.
@@ -50,7 +51,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.aoi import log_aoi
-from repro.core.asymmetric_batched import P_MIN, best_response_given_slope
+from repro.core.asymmetric_batched import (P_MIN, _gs_fixed_point,
+                                           best_response_given_slope)
 from repro.core.duration import DurationModel
 from repro.core.poibin import (poibin_convolve, poibin_pmf_loo,
                                poibin_pmf_recursive)
@@ -68,69 +70,35 @@ __all__ = [
 
 
 # ---------------------------------------------------------------------------
-# masked inner game: Gauss-Seidel NE of one coalition at full fleet width
+# inner game: Gauss-Seidel NE of one coalition at full fleet width, the
+# heterogeneous engine's sweep with a member mask (``_gs_fixed_point``)
 # ---------------------------------------------------------------------------
-
-def _masked_gs(costs, gammas, d_tab, member, p0, *, damping, max_iters, tol):
-    """Damped Gauss-Seidel NE of the subgame on ``member`` at width N.
-
-    Identical op sequence to ``asymmetric_batched._gs_fixed_point`` with
-    one masked select at the update: non-members are held at ``p = 0``
-    exactly, whose ``[1, 0]`` Bernoulli factor deconvolves/convolves as an
-    identity (``poibin_pmf_loo`` at ``p = 0`` is a copy), so with an
-    all-true mask every intermediate — and the fixed point — is bitwise
-    the unmasked solver's.
-    """
-    n = costs.shape[0]
-    dd = d_tab[1:] - d_tab[:-1]
-
-    def sweep(p):
-        f = poibin_pmf_recursive(p)
-
-        def node(carry, i):
-            f, p = carry
-            pi = p[i]
-            loo = poibin_pmf_loo(f, pi)
-            slope = -(loo[:-1] @ dd)
-            br = best_response_given_slope(slope, costs[i], gammas[i])
-            upd = (1.0 - damping) * pi + damping * br
-            new_pi = jnp.where(member[i], upd, 0.0)
-            f_new = poibin_convolve(loo, new_pi)
-            return (f_new, p.at[i].set(new_pi)), jnp.abs(new_pi - pi)
-
-        (_, p_new), deltas = jax.lax.scan(node, (f, p), jnp.arange(n))
-        return p_new, jnp.max(deltas)
-
-    def cond(state):
-        _, delta, it = state
-        return (delta >= tol) & (it < max_iters)
-
-    def body(state):
-        p, _, it = state
-        p_new, delta = sweep(p)
-        return p_new, delta, it + 1
-
-    p, delta, iters = jax.lax.while_loop(
-        cond, body, (p0, jnp.asarray(jnp.inf, p0.dtype), jnp.asarray(0)))
-    return p, delta < tol, iters
-
 
 def _member_matrix(assign, m):
     """(M, N) bool coalition-membership masks from an (N,) assignment."""
     return jnp.arange(m)[:, None] == assign[None, :]
 
 
+def _solve_masked(costs, gammas, d_tab, masks, *, damping, max_iters, tol):
+    """Inner NE of each subgame in ``masks`` (``(K, N)`` bool) at width N,
+    in one batch: ``(K, N)`` profiles (zeros off each subgame) and ``(K,)``
+    convergence flags."""
+    k = masks.shape[0]
+    p0 = jnp.where(masks, 0.5, 0.0).astype(d_tab.dtype)
+    p, conv, _ = _gs_fixed_point(
+        jnp.broadcast_to(costs, masks.shape),
+        jnp.broadcast_to(gammas, masks.shape),
+        jnp.broadcast_to(d_tab, (k,) + d_tab.shape), p0, damping=damping,
+        max_iters=max_iters, tol=tol, member=masks)
+    return p, conv
+
+
 def _solve_coalitions(costs, gammas, d_tab, member, *, damping, max_iters,
                       tol):
     """Inner NE of every coalition: (M, N) profiles (zeros off-coalition),
     (M,) convergence flags, and (M,) expected durations E[D_{S_c}]."""
-    def one(mask):
-        p0 = jnp.where(mask, 0.5, 0.0).astype(d_tab.dtype)
-        p, conv, _ = _masked_gs(costs, gammas, d_tab, mask, p0,
-                                damping=damping, max_iters=max_iters, tol=tol)
-        return p, conv
-
-    p_cs, conv = jax.vmap(one)(member)
+    p_cs, conv = _solve_masked(costs, gammas, d_tab, member, damping=damping,
+                               max_iters=max_iters, tol=tol)
     e_d = jax.vmap(poibin_pmf_recursive)(p_cs) @ d_tab
     return p_cs, conv, e_d
 
@@ -157,11 +125,10 @@ def _candidate_gains(costs, gammas, d_tab, assign, cap, *, m, damping,
 
     # candidate masks: node i joins coalition c → S_c ∪ {i}, (N, M, N)
     cand = member[None, :, :] | jnp.eye(n, dtype=bool)[:, None, :]
-    p_cand, conv_cand = jax.vmap(jax.vmap(
-        lambda mask: _masked_gs(
-            costs, gammas, d_tab, mask,
-            jnp.where(mask, 0.5, 0.0).astype(d_tab.dtype),
-            damping=damping, max_iters=max_iters, tol=tol)[:2]))(cand)
+    p_cand, conv_cand = _solve_masked(
+        costs, gammas, d_tab, cand.reshape(n * m, n), damping=damping,
+        max_iters=max_iters, tol=tol)
+    p_cand, conv_cand = p_cand.reshape(n, m, n), conv_cand.reshape(n, m)
     e_d_cand = jax.vmap(jax.vmap(poibin_pmf_recursive))(p_cand) @ d_tab
     p_i_cand = p_cand[jnp.arange(n), :, jnp.arange(n)]          # (N, M)
     u_cand = (-e_d_cand - gammas[:, None] * log_aoi(p_i_cand)

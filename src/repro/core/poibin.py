@@ -23,6 +23,11 @@ import jax
 import jax.numpy as jnp
 
 __all__ = [
+    "unit_roots",
+    "chi_size",
+    "poibin_chi_fold",
+    "poibin_chi",
+    "poibin_chi_suffixes",
     "poibin_pmf",
     "poibin_pmf_recursive",
     "poibin_convolve",
@@ -36,6 +41,67 @@ __all__ = [
 ]
 
 
+def unit_roots(size: int) -> tuple[jax.Array, jax.Array]:
+    """``(cos, sin)`` of the ``size`` points ``w^n = exp(j 2 pi n/size)``."""
+    ang = 2 * jnp.pi * jnp.arange(size) / size
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def chi_size(n_nodes: int) -> int:
+    """Odd transform length ``>= n_nodes + 1`` for the solver's spectra.
+
+    A real pmf's characteristic function is conjugate-symmetric,
+    ``chi[size - n] = conj(chi[n])``. At an odd length every point but
+    ``n = 0`` has such a partner, so the first ``size // 2 + 1`` points
+    carry the whole spectrum, with no lone point at ``w = -1``. Any length
+    above the support recovers the pmf exactly.
+    """
+    return n_nodes + 1 if n_nodes % 2 == 0 else n_nodes + 2
+
+
+def poibin_chi_fold(chi, p_k: jax.Array, w: tuple[jax.Array, jax.Array]
+                    ) -> tuple[jax.Array, jax.Array]:
+    """Multiply node k's Bernoulli factor ``1 - p_k + p_k w`` into ``chi``
+    at the points ``w``. At ``p_k = 0`` the factor is exactly ``1``, so
+    ``chi`` comes back unchanged."""
+    re, im = chi
+    w_re, w_im = w
+    # Materialise the factor once: fused into the product, the TPU's
+    # float64 emulation recomputes it for each part of the product.
+    t_re, t_im = jax.lax.optimization_barrier(
+        (p_k * (w_re - 1.0) + 1.0, p_k * w_im))
+    return re * t_re - im * t_im, re * t_im + im * t_re
+
+
+def poibin_chi(p: jax.Array, w: tuple[jax.Array, jax.Array]
+               ) -> tuple[jax.Array, jax.Array]:
+    """Characteristic function ``prod_k (1 - p_k + p_k w)`` at the points
+    ``w`` (from :func:`unit_roots`), as a (re, im) pair: the TPU compiler
+    has no complex128."""
+    def step(chi, p_k):
+        return poibin_chi_fold(chi, p_k, w), None
+
+    one = jnp.ones(w[0].shape, jnp.result_type(p, w[0]))
+    chi, _ = jax.lax.scan(step, (one, jnp.zeros_like(one)), p)
+    return chi
+
+
+def poibin_chi_suffixes(p: jax.Array, w: tuple[jax.Array, jax.Array],
+                        chi0: tuple[jax.Array, jax.Array]
+                        ) -> tuple[jax.Array, jax.Array]:
+    """Exclusive suffix products ``chi0 * prod_{k > i} (1 - p_k + p_k w)``
+    for every node i, stacked on a new leading axis.
+
+    Node i's leave-one-out characteristic function is the product of the
+    factors before it (:func:`poibin_chi` of ``p[:i]``) and this suffix
+    with ``chi0 = 1``: products only, so nothing is divided out."""
+    def step(chi, p_k):
+        return poibin_chi_fold(chi, p_k, w), chi
+
+    _, out = jax.lax.scan(step, chi0, p, reverse=True)
+    return out
+
+
 @jax.jit
 def poibin_pmf(p: jax.Array) -> jax.Array:
     """Pmf of the Poisson-Binomial distribution via the DFT closed form.
@@ -45,9 +111,7 @@ def poibin_pmf(p: jax.Array) -> jax.Array:
         P[m] = (1/(N+1)) * sum_{n=0}^{N} exp(-j 2 pi n m/(N+1))
                   * prod_{k=1}^{N} [p_k (exp(j 2 pi n/(N+1)) - 1) + 1]
 
-    in real arithmetic: the characteristic function is carried as a
-    (re, im) pair, as in the Pallas kernel, because the TPU compiler has no
-    complex128.
+    in real arithmetic (:func:`poibin_chi`).
 
     Args:
         p: ``(N,)`` participation probabilities in [0, 1].
@@ -57,19 +121,7 @@ def poibin_pmf(p: jax.Array) -> jax.Array:
     """
     p = jnp.asarray(p)
     size = p.shape[0] + 1
-    # Characteristic function on the (N+1)-point unit circle,
-    # prod_k [p_k (w - 1) + 1] for each frequency w = exp(j 2 pi n/(N+1)).
-    ang = 2 * jnp.pi * jnp.arange(size) / size
-    w_re, w_im = jnp.cos(ang), jnp.sin(ang)
-
-    def factor(chi, p_k):
-        re, im = chi
-        t_re = p_k * (w_re - 1.0) + 1.0
-        t_im = p_k * w_im
-        return (re * t_re - im * t_im, re * t_im + im * t_re), None
-
-    one = jnp.ones((size,), jnp.result_type(p, w_re))
-    (chi_re, chi_im), _ = jax.lax.scan(factor, (one, jnp.zeros_like(one)), p)
+    chi_re, chi_im = poibin_chi(p, unit_roots(size))
     # Re[sum_n exp(-j theta_mn) chi_n] with theta_mn = 2 pi m n/(N+1).
     theta = 2 * jnp.pi * jnp.outer(jnp.arange(size), jnp.arange(size)) / size
     pmf = jnp.sum(jnp.cos(theta) * chi_re + jnp.sin(theta) * chi_im,
@@ -102,8 +154,8 @@ def poibin_convolve(pmf: jax.Array, p_k: jax.Array) -> jax.Array:
 
     ``pmf`` is a fixed-length ``(S,)`` array whose top entry must be zero
     (the support grows by one); the result stays ``(S,)``. This is the single
-    step of :func:`poibin_pmf_recursive` exposed so the heterogeneous-game
-    engine can do incremental Gauss-Seidel pmf updates in O(N) instead of a
+    step of :func:`poibin_pmf_recursive` exposed so the heterogeneous
+    planners' coordinate descent can update the pmf in O(N) instead of a
     full O(N²) recompute per node.
     """
     shifted = jnp.concatenate([jnp.zeros((1,), pmf.dtype), pmf[:-1]])

@@ -400,7 +400,7 @@ class SweepService:
                 def lower_solve():
                     fn = functools.partial(_gs_fixed_point, damping=damping,
                                            max_iters=max_iters, tol=tol)
-                    return jax.jit(jax.vmap(fn), in_shardings=sharding,
+                    return jax.jit(fn, in_shardings=sharding,
                                    out_shardings=sharding).lower(*shapes)
 
                 def lower_verify():

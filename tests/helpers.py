@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.asymmetric_batched import verify_equilibrium_batched
 from repro.core.duration import DurationModel
@@ -72,3 +73,54 @@ def assert_heterogeneous_ne(costs, gammas, dur, p, tol: float = NE_TOL,
     assert gain <= tol, (
         f"profitable deviation {gain:.3e} > {tol:.1e} from profile "
         f"{[round(float(x), 4) for x in jnp.asarray(p)]}")
+
+
+def _best_response(a, gamma):
+    """Maximiser over [P_MIN, 1] of ``a p - gamma log(1/p - 1/2)``: the
+    root of ``p (2 - p) = -2 gamma / a`` when a < 0, else a corner."""
+    with np.errstate(divide="ignore", invalid="ignore"):
+        interior = 1.0 - np.sqrt(np.clip(1.0 + 2.0 * gamma / a, 0.0, 1.0))
+    interior = np.clip(interior, P_MIN, 1.0)
+    return np.where(gamma <= 0.0, np.where(a > 0.0, 1.0, P_MIN),
+                    np.where(a >= 0.0, 1.0, interior))
+
+
+def gauss_seidel_reference(costs, gammas, d_tab, *, damping: float = 0.5,
+                           max_iters: int = 200, tol: float = 1e-5,
+                           p0: float = 0.5):
+    """Damped Gauss-Seidel best responses of a ``(B, N)`` batch, in numpy.
+
+    Node i's opponents' pmf is built directly, as the product of the other
+    nodes' Bernoulli factors, at every node step (no deconvolution, no
+    transform). Sweeps stop per fleet when no node moved by ``tol``, as in
+    :func:`repro.core.asymmetric_batched.solve_heterogeneous`. Returns
+    ``(p, converged, iters)``.
+    """
+    costs, gammas = np.asarray(costs), np.asarray(gammas)
+    d_tab = np.asarray(d_tab)
+    b, n = costs.shape
+    dd = d_tab[1:] - d_tab[:-1]
+    p = np.full((b, n), p0)
+    iters = np.zeros(b, np.int64)
+    done = np.zeros(b, bool)
+    for _ in range(max_iters):
+        active = ~done
+        delta = np.zeros(b)
+        for i in range(n):
+            g = np.zeros((b, n))
+            g[:, 0] = 1.0
+            for j in range(n):
+                if j != i:
+                    pj = p[:, j:j + 1]
+                    g[:, 1:] = g[:, 1:] * (1.0 - pj) + g[:, :-1] * pj
+                    g[:, 0] *= 1.0 - pj[:, 0]
+            br = _best_response(-(g @ dd) - costs[:, i], gammas[:, i])
+            new = (1.0 - damping) * p[:, i] + damping * br
+            delta = np.maximum(delta, np.where(active,
+                                               np.abs(new - p[:, i]), 0.0))
+            p[:, i] = np.where(active, new, p[:, i])
+        iters += active
+        done |= active & (delta < tol)
+        if done.all():
+            break
+    return p, done, iters

@@ -1,4 +1,5 @@
 """Batched heterogeneous-equilibrium engine vs the scalar seed oracles."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -17,7 +18,7 @@ from repro.core.asymmetric_batched import (P_MIN, best_response_given_slope,
                                            verify_equilibrium_batched)
 from repro.core.poibin import (poibin_convolve, poibin_pmf_loo,
                                poibin_pmf_recursive)
-from helpers import assert_heterogeneous_ne
+from helpers import assert_heterogeneous_ne, gauss_seidel_reference
 
 N = 10
 
@@ -44,6 +45,37 @@ def test_engine_matches_reference_loop(game):
     assert it_ref == it_new
     np.testing.assert_allclose(np.asarray(p_new), np.asarray(p_ref),
                                atol=1e-12)
+
+
+# The N=50 fleets of the ne-hetero-n50 benchmark pool (costs U(0.5, 12), AoI
+# weights U(0.2, 1), drawn as bench/inputs.ne_fleets draws batch j from
+# fold_in(PRNGKey(2503), j)) on which an incremental pmf deconvolution
+# drifted off the direct Gauss-Seidel path: (batch, fleet).
+POOL_FLEETS = [(3, 195), (3, 38), (3, 325), (5, 109)]
+
+
+@pytest.fixture(scope="module")
+def dur50():
+    return C.theoretical_duration(n_nodes=50, d_inf=35.0, slope=4.0,
+                                  horizon=500.0)
+
+
+@pytest.mark.parametrize("batch,fleet", POOL_FLEETS)
+def test_engine_matches_direct_gauss_seidel_n50(dur50, batch, fleet):
+    """At N=50 the engine follows the direct Gauss-Seidel sweep (the
+    others' pmf as a product of their factors at every node step): the
+    same profile to 1e-10 after the same number of sweeps."""
+    key = jax.random.fold_in(jax.random.PRNGKey(2503), batch)
+    kc, kg = jax.random.split(key)
+    costs = jax.random.uniform(kc, (512, 50), jnp.float64, 0.5, 12.0)
+    gammas = jax.random.uniform(kg, (512, 50), jnp.float64, 0.2, 1.0)
+    costs, gammas = costs[fleet:fleet + 1], gammas[fleet:fleet + 1]
+    p_ref, conv_ref, it_ref = gauss_seidel_reference(costs, gammas,
+                                                     dur50.table())
+    sol = solve_heterogeneous(costs, gammas, dur50)
+    assert bool(sol.converged[0]) == bool(conv_ref[0])
+    assert int(sol.iters[0]) == int(it_ref[0])
+    np.testing.assert_allclose(np.asarray(sol.p), p_ref, rtol=0, atol=1e-10)
 
 
 def test_verify_matches_reference(game):

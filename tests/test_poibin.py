@@ -1,4 +1,5 @@
 """Poisson-Binomial pmf (paper eq. 9) and expected duration (eq. 8)."""
+import functools
 import itertools
 
 import jax
@@ -7,8 +8,10 @@ import numpy as np
 import pytest
 
 import repro.core  # noqa: F401  (enables x64)
-from repro.core.poibin import (expected_duration, poibin_mean, poibin_pmf,
-                               poibin_pmf_recursive, symmetric_pmf)
+from repro.core.poibin import (chi_size, expected_duration, poibin_chi,
+                               poibin_chi_fold, poibin_chi_suffixes,
+                               poibin_mean, poibin_pmf, poibin_pmf_recursive,
+                               symmetric_pmf, unit_roots)
 
 
 def brute_force_pmf(p):
@@ -75,3 +78,76 @@ def test_gradient_flows_through_pmf():
     assert np.all(np.isfinite(np.asarray(g)))
     # E[D] = E[k] here, so gradient wrt each p_i is exactly 1
     np.testing.assert_allclose(np.asarray(g), 1.0, atol=1e-8)
+
+
+def direct_pmf(p):
+    """Pmf of sum of Bernoulli(p_k) by multiplying in one factor at a time."""
+    pmf = np.array([1.0])
+    for pk in p:
+        pmf = np.convolve(pmf, [1.0 - pk, pk])
+    return pmf
+
+
+def inverse_dft(chi):
+    """Real pmf over m = 0..size-1 from a characteristic function given
+    at the ``size`` points exp(j 2 pi n/size)."""
+    spec = np.asarray(chi[0]) + 1j * np.asarray(chi[1])
+    size = spec.shape[0]
+    m = np.arange(size)
+    return (np.exp(-2j * np.pi * np.outer(m, m) / size) @ spec).real / size
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def chi_leave_one_out(p, i, w):
+    """Node i's leave-one-out characteristic function: the product of the
+    factors before it times the suffix product of those after it."""
+    one = jnp.ones(w[0].shape, p.dtype)
+    suf_re, suf_im = poibin_chi_suffixes(p, w, (one, jnp.zeros_like(one)))
+    pre_re, pre_im = poibin_chi(p[:i], w)
+    return (pre_re * suf_re[i] - pre_im * suf_im[i],
+            pre_re * suf_im[i] + pre_im * suf_re[i])
+
+
+@pytest.mark.parametrize("n", [1, 2, 50])
+@pytest.mark.parametrize("p_i", [0.0, 0.5, 1.0, None])
+def test_chi_leave_one_out_matches_direct_pmf_of_others(n, p_i):
+    """Prefix times suffix products leave out node i's factor: the
+    inverse transform is the others' pmf, built directly."""
+    rng = np.random.default_rng(7 + n)
+    p = rng.uniform(0.0, 1.0, n)
+    i = n // 2
+    if p_i is not None:
+        p[i] = p_i
+    size = chi_size(n)
+    assert size % 2 == 1 and size >= n + 1
+    w = unit_roots(size)
+    got = inverse_dft(chi_leave_one_out(jnp.asarray(p), i, w))
+    want = direct_pmf(np.delete(p, i))
+    np.testing.assert_allclose(got[:n], want, rtol=0, atol=1e-13)
+    np.testing.assert_allclose(got[n:], 0.0, rtol=0, atol=1e-13)
+
+
+@pytest.mark.parametrize("n", [1, 2, 50])
+def test_chi_factor_at_zero_is_bitwise_identity(n):
+    """p = 0's factor is exactly 1: folding it in leaves every bit of the
+    characteristic function in place, and a suffix product steps over it
+    unchanged."""
+    rng = np.random.default_rng(11)
+    w = unit_roots(chi_size(n))
+    chi = poibin_chi(jnp.asarray(rng.uniform(0.0, 1.0, n)), w)
+    for a, b in zip(poibin_chi_fold(chi, jnp.asarray(0.0), w), chi):
+        np.testing.assert_array_equal(np.asarray(a).view(np.int64),
+                                      np.asarray(b).view(np.int64))
+    p = jnp.asarray(rng.uniform(0.0, 1.0, n + 1)).at[1].set(0.0)
+    suf = poibin_chi_suffixes(p, w, chi)
+    for part in suf:
+        np.testing.assert_array_equal(np.asarray(part[0]).view(np.int64),
+                                      np.asarray(part[1]).view(np.int64))
+
+
+def test_pmf_keeps_its_chi_product():
+    """poibin_pmf's DFT inverts the shared characteristic-function product."""
+    p = jnp.asarray(np.random.default_rng(3).uniform(0.0, 1.0, 9))
+    chi = poibin_chi(p, unit_roots(10))
+    np.testing.assert_allclose(np.asarray(poibin_pmf(p)), inverse_dft(chi),
+                               rtol=0, atol=1e-14)
