@@ -14,8 +14,10 @@ One chip:
 
 1. device   -- a TPU, or exit non-zero naming the platform found;
 2. game     -- heterogeneous NE solve -> certification -> PoA report and a
-               symmetric (gamma, c) grid at the paper's N=50, one Mechanism
-               PoA evaluation, a small coalition partition solve;
+               symmetric (gamma, c) grid at the paper's N=50, certificates
+               of two benchmark pool batches held to a direct numpy one,
+               one Mechanism PoA evaluation, a small coalition partition
+               solve;
 3. kernels  -- the Pallas FedAvg merge and Poisson-binomial kernels,
                compiled (not interpreted) and held to the jnp references;
 4. campaign -- ResNet-18 at its published widths (11,173,962 params) through
@@ -54,6 +56,11 @@ RESNET18_PARAMS = 11_173_962
 NE_TOL = 1e-4                 # NE certification bound of tests/helpers.py
 PARITY = 2e-6                 # pallas vs ref, sharded vs one device
 GAME_FLEETS = 256             # heterogeneous fleets per sweep
+# Batches of the ne-hetero-n50 benchmark pool (fold_in(PRNGKey(2503), j))
+# whose certificates read up to 1.4e-7 off a direct one at the host's
+# duration table while the table's fit ran on the chip.
+CERT_BATCHES = (2, 6)
+CERT_GAP = 1e-8               # the sound readings are ~2e-10
 # One vmapped campaign round holds every client's params, gradients and
 # activations, ~0.55 GB per (scenario, client) at full width: the paper's
 # N=50 needs ~27 GB, over the 16 GB of HBM. N=8 x B=2 compiles to ~9 GB
@@ -154,6 +161,32 @@ def game_heterogeneous() -> str:
     return (f"{int(jnp.sum(sol.converged))}/{GAME_FLEETS} converged, all "
             f"certified (max deviation {max_dev:.2e} <= {NE_TOL:g}); "
             f"PoA in [{poa.min():.4f}, {poa.max():.4f}]")
+
+
+def game_certificate() -> str:
+    from repro.core import (solve_heterogeneous, theoretical_duration,
+                            verify_equilibrium_batched)
+
+    sys.path.insert(0, str(Path(__file__).resolve().parent / "tests"))
+    from helpers import direct_deviation
+
+    dur = theoretical_duration(N_PAPER, d_inf=35.0, slope=4.0, horizon=500.0)
+    with jax.default_device(jax.devices("cpu")[0]):
+        host_table = np.asarray(dur.table())
+    gap = 0.0
+    for j in CERT_BATCHES:
+        kc, kg = jax.random.split(
+            jax.random.fold_in(jax.random.PRNGKey(2503), j))
+        costs = jax.random.uniform(kc, (512, N_PAPER), jnp.float64, 0.5, 12.0)
+        gammas = jax.random.uniform(kg, (512, N_PAPER), jnp.float64, 0.2, 1.0)
+        sol = solve_heterogeneous(costs, gammas, dur)
+        dev = verify_equilibrium_batched(costs, gammas, dur, sol.p)
+        want = direct_deviation(costs, gammas, host_table, sol.p)
+        gap = max(gap, float(np.max(np.abs(np.asarray(dev) - want))))
+    _require(gap <= CERT_GAP, f"certificate off the direct one by {gap}")
+    return (f"pool batches {list(CERT_BATCHES)}: certificate within "
+            f"{gap:.2e} of the direct one at the host's table "
+            f"(<= {CERT_GAP:g})")
 
 
 def game_symmetric() -> str:
@@ -526,6 +559,8 @@ def main(argv: list[str] | None = None) -> int:
         _require(count >= 1, "a device")
         _phase(log, "game.heterogeneous",
                f"B={GAME_FLEETS} N={N_PAPER}", game_heterogeneous)
+        _phase(log, "game.certificate",
+               f"B=512 x {len(CERT_BATCHES)} N={N_PAPER}", game_certificate)
         _phase(log, "game.symmetric", f"8x4 (gamma, c) grid N={N_PAPER}",
                game_symmetric)
         _phase(log, "game.mechanism", f"N={N_PAPER}", game_mechanism)

@@ -57,11 +57,11 @@ import jax.numpy as jnp
 
 from repro.core.aoi import log_aoi
 from repro.core.duration import DurationModel
-from repro.core.poibin import (chi_size, poibin_chi_fold,
-                               poibin_chi_suffixes, poibin_convolve,
-                               poibin_pmf_batched, poibin_pmf_loo,
-                               poibin_pmf_loo_all, poibin_pmf_recursive,
-                               unit_roots)
+from repro.core.poibin import (chi_size, materialised_factor,
+                               poibin_chi_fold, poibin_chi_suffixes,
+                               poibin_convolve, poibin_pmf_batched,
+                               poibin_pmf_loo, poibin_pmf_loo_all,
+                               poibin_pmf_recursive, unit_roots)
 
 __all__ = [
     "P_MIN",
@@ -109,7 +109,7 @@ def best_response_given_slope(slope: jax.Array, cost: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Gauss-Seidel fixed point (single scenario; vmapped by the public wrapper)
+# Gauss-Seidel fixed point (batched by hand: fleets on the last axis)
 # ---------------------------------------------------------------------------
 
 def _gs_fixed_point(costs, gammas, d_tab, p0, *, damping, max_iters, tol,
@@ -159,7 +159,7 @@ def _gs_fixed_point(costs, gammas, d_tab, p0, *, damping, max_iters, tol,
         new_pi = (1.0 - damping) * pi + damping * br
         if keep:
             new_pi = jnp.where(keep[0], new_pi, 0.0)
-        return poibin_chi_fold(pre, new_pi, w), new_pi
+        return poibin_chi_fold(pre, materialised_factor(new_pi, w)), new_pi
 
     def sweep(p):
         t = poibin_chi_suffixes(p, w, (c_re, c_im))
@@ -187,6 +187,9 @@ def _gs_fixed_point(costs, gammas, d_tab, p0, *, damping, max_iters, tol,
     return p.T, delta < tol, iters
 
 
+# The name is kept, though nothing here is vmapped: device-trace breakdowns
+# list the solve's ops under ``jit__solve_vmapped``, so readings stay
+# comparable across versions.
 @functools.partial(jax.jit, static_argnames=("damping", "max_iters", "tol"))
 def _solve_vmapped(costs, gammas, d_tab, p0, *, damping, max_iters, tol):
     return _gs_fixed_point(costs, gammas, d_tab, p0, damping=damping,

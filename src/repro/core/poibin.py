@@ -25,6 +25,8 @@ import jax.numpy as jnp
 __all__ = [
     "unit_roots",
     "chi_size",
+    "bernoulli_factor",
+    "materialised_factor",
     "poibin_chi_fold",
     "poibin_chi",
     "poibin_chi_suffixes",
@@ -59,17 +61,28 @@ def chi_size(n_nodes: int) -> int:
     return n_nodes + 1 if n_nodes % 2 == 0 else n_nodes + 2
 
 
-def poibin_chi_fold(chi, p_k: jax.Array, w: tuple[jax.Array, jax.Array]
-                    ) -> tuple[jax.Array, jax.Array]:
-    """Multiply node k's Bernoulli factor ``1 - p_k + p_k w`` into ``chi``
-    at the points ``w``. At ``p_k = 0`` the factor is exactly ``1``, so
-    ``chi`` comes back unchanged."""
-    re, im = chi
+def bernoulli_factor(p_k: jax.Array, w: tuple[jax.Array, jax.Array]
+                     ) -> tuple[jax.Array, jax.Array]:
+    """Node k's factor ``1 - p_k + p_k w`` at the points ``w``, as a
+    (re, im) pair. At ``p_k = 0`` it is exactly ``(1, 0)``."""
     w_re, w_im = w
-    # Materialise the factor once: fused into the product, the TPU's
-    # float64 emulation recomputes it for each part of the product.
-    t_re, t_im = jax.lax.optimization_barrier(
-        (p_k * (w_re - 1.0) + 1.0, p_k * w_im))
+    return p_k * (w_re - 1.0) + 1.0, p_k * w_im
+
+
+def materialised_factor(p_k: jax.Array, w: tuple[jax.Array, jax.Array]
+                        ) -> tuple[jax.Array, jax.Array]:
+    """:func:`bernoulli_factor`, computed once. Fused into a product, the
+    TPU's float64 emulation recomputes the factor for each part of the
+    product; the Gauss-Seidel solver's steps, which do little else, halve
+    their work by materialising it."""
+    return jax.lax.optimization_barrier(bernoulli_factor(p_k, w))
+
+
+def poibin_chi_fold(chi, t) -> tuple[jax.Array, jax.Array]:
+    """``chi`` times a factor ``t`` (both (re, im) pairs). The factor of
+    ``p_k = 0`` is exactly ``1``, so ``chi`` comes back unchanged."""
+    re, im = chi
+    t_re, t_im = t
     return re * t_re - im * t_im, re * t_im + im * t_re
 
 
@@ -79,7 +92,7 @@ def poibin_chi(p: jax.Array, w: tuple[jax.Array, jax.Array]
     ``w`` (from :func:`unit_roots`), as a (re, im) pair: the TPU compiler
     has no complex128."""
     def step(chi, p_k):
-        return poibin_chi_fold(chi, p_k, w), None
+        return poibin_chi_fold(chi, bernoulli_factor(p_k, w)), None
 
     one = jnp.ones(w[0].shape, jnp.result_type(p, w[0]))
     chi, _ = jax.lax.scan(step, (one, jnp.zeros_like(one)), p)
@@ -96,7 +109,7 @@ def poibin_chi_suffixes(p: jax.Array, w: tuple[jax.Array, jax.Array],
     factors before it (:func:`poibin_chi` of ``p[:i]``) and this suffix
     with ``chi0 = 1``: products only, so nothing is divided out."""
     def step(chi, p_k):
-        return poibin_chi_fold(chi, p_k, w), chi
+        return poibin_chi_fold(chi, materialised_factor(p_k, w)), chi
 
     _, out = jax.lax.scan(step, chi0, p, reverse=True)
     return out

@@ -75,6 +75,34 @@ def assert_heterogeneous_ne(costs, gammas, dur, p, tol: float = NE_TOL,
         f"{[round(float(x), 4) for x in jnp.asarray(p)]}")
 
 
+def direct_deviation(costs, gammas, d_tab, p, *, grid: int = 64):
+    """``(B,)`` largest gain of a unilateral deviation to a grid point, in
+    numpy: each node's opponents' pmf built directly as the product of
+    their Bernoulli factors (no deconvolution), the gain of node i moving
+    from p_i to q as ``(p_i - q)(slope_i + c_i) - gamma_i (log_aoi(q) -
+    log_aoi(p_i))``, floored at 0."""
+    costs, gammas, p = (np.asarray(x) for x in (costs, gammas, p))
+    d_tab = np.asarray(d_tab)
+    b, n = p.shape
+    g = np.zeros((b, n, n))
+    g[:, :, 0] = 1.0
+    for j in range(n):
+        pj = np.broadcast_to(p[:, j:j + 1], (b, n)).copy()
+        pj[:, j] = 0.0                      # node j is not its own opponent
+        g[:, :, 1:] = g[:, :, 1:] * (1.0 - pj[..., None]) \
+            + g[:, :, :-1] * pj[..., None]
+        g[:, :, 0] *= 1.0 - pj
+    slope = g @ (d_tab[1:] - d_tab[:-1])   # E[D] rise per unit of p_i
+
+    def log_aoi(x):
+        return np.log(1.0 / x - 0.5)
+
+    q = np.linspace(P_MIN, 1.0, grid)
+    gain = ((p - q[:, None, None]) * (slope + costs)
+            - gammas * (log_aoi(q)[:, None, None] - log_aoi(p)))
+    return np.maximum(gain.max(axis=(0, 2)), 0.0)
+
+
 def _best_response(a, gamma):
     """Maximiser over [P_MIN, 1] of ``a p - gamma log(1/p - 1/2)``: the
     root of ``p (2 - p) = -2 gamma / a`` when a < 0, else a corner."""

@@ -18,7 +18,8 @@ from repro.core.asymmetric_batched import (P_MIN, best_response_given_slope,
                                            verify_equilibrium_batched)
 from repro.core.poibin import (poibin_convolve, poibin_pmf_loo,
                                poibin_pmf_recursive)
-from helpers import assert_heterogeneous_ne, gauss_seidel_reference
+from helpers import (assert_heterogeneous_ne, direct_deviation,
+                     gauss_seidel_reference)
 
 N = 10
 
@@ -54,6 +55,21 @@ def test_engine_matches_reference_loop(game):
 POOL_FLEETS = [(3, 195), (3, 38), (3, 325), (5, 109)]
 
 
+# Pool fleets whose answers hold nodes at p = 1. Their certificates are
+# the pool's most sensitive to the duration table: fitted on a v5e,
+# the table moved them 1.4e-7 (2, 192) and 1.1e-8 (6, 173) off the direct
+# certificate at the host's fit.
+CERT_FLEETS = [(2, 192), (6, 173)]
+
+
+def _pool_fleet(batch, fleet):
+    key = jax.random.fold_in(jax.random.PRNGKey(2503), batch)
+    kc, kg = jax.random.split(key)
+    costs = jax.random.uniform(kc, (512, 50), jnp.float64, 0.5, 12.0)
+    gammas = jax.random.uniform(kg, (512, 50), jnp.float64, 0.2, 1.0)
+    return costs[fleet:fleet + 1], gammas[fleet:fleet + 1]
+
+
 @pytest.fixture(scope="module")
 def dur50():
     return C.theoretical_duration(n_nodes=50, d_inf=35.0, slope=4.0,
@@ -65,17 +81,25 @@ def test_engine_matches_direct_gauss_seidel_n50(dur50, batch, fleet):
     """At N=50 the engine follows the direct Gauss-Seidel sweep (the
     others' pmf as a product of their factors at every node step): the
     same profile to 1e-10 after the same number of sweeps."""
-    key = jax.random.fold_in(jax.random.PRNGKey(2503), batch)
-    kc, kg = jax.random.split(key)
-    costs = jax.random.uniform(kc, (512, 50), jnp.float64, 0.5, 12.0)
-    gammas = jax.random.uniform(kg, (512, 50), jnp.float64, 0.2, 1.0)
-    costs, gammas = costs[fleet:fleet + 1], gammas[fleet:fleet + 1]
+    costs, gammas = _pool_fleet(batch, fleet)
     p_ref, conv_ref, it_ref = gauss_seidel_reference(costs, gammas,
                                                      dur50.table())
     sol = solve_heterogeneous(costs, gammas, dur50)
     assert bool(sol.converged[0]) == bool(conv_ref[0])
     assert int(sol.iters[0]) == int(it_ref[0])
     np.testing.assert_allclose(np.asarray(sol.p), p_ref, rtol=0, atol=1e-10)
+
+
+@pytest.mark.parametrize("batch,fleet", CERT_FLEETS)
+def test_verify_matches_direct_certificate_n50(dur50, batch, fleet):
+    """The certificate of the engine's N=50 answer is the direct one: each
+    node's opponents' pmf as a product of their factors, no deconvolution."""
+    costs, gammas = _pool_fleet(batch, fleet)
+    sol = solve_heterogeneous(costs, gammas, dur50)
+    dev = verify_equilibrium_batched(costs, gammas, dur50, sol.p)
+    want = direct_deviation(costs, gammas, dur50.table(), sol.p)
+    assert 0.0 < want[0] < 1e-4
+    np.testing.assert_allclose(np.asarray(dev), want, rtol=0, atol=1e-10)
 
 
 def test_verify_matches_reference(game):

@@ -8,8 +8,10 @@ import numpy as np
 import pytest
 
 import repro.core  # noqa: F401  (enables x64)
-from repro.core.poibin import (chi_size, expected_duration, poibin_chi,
-                               poibin_chi_fold, poibin_chi_suffixes,
+from repro.core.poibin import (bernoulli_factor, chi_size,
+                               expected_duration, materialised_factor,
+                               poibin_chi, poibin_chi_fold,
+                               poibin_chi_suffixes,
                                poibin_mean, poibin_pmf, poibin_pmf_recursive,
                                symmetric_pmf, unit_roots)
 
@@ -135,9 +137,11 @@ def test_chi_factor_at_zero_is_bitwise_identity(n):
     rng = np.random.default_rng(11)
     w = unit_roots(chi_size(n))
     chi = poibin_chi(jnp.asarray(rng.uniform(0.0, 1.0, n)), w)
-    for a, b in zip(poibin_chi_fold(chi, jnp.asarray(0.0), w), chi):
-        np.testing.assert_array_equal(np.asarray(a).view(np.int64),
-                                      np.asarray(b).view(np.int64))
+    zero = jnp.asarray(0.0)
+    for factor in (bernoulli_factor(zero, w), materialised_factor(zero, w)):
+        for a, b in zip(poibin_chi_fold(chi, factor), chi):
+            np.testing.assert_array_equal(np.asarray(a).view(np.int64),
+                                          np.asarray(b).view(np.int64))
     p = jnp.asarray(rng.uniform(0.0, 1.0, n + 1)).at[1].set(0.0)
     suf = poibin_chi_suffixes(p, w, chi)
     for part in suf:
